@@ -1096,6 +1096,8 @@ def main(argv=None) -> None:
         del sels[i:i + 2]
         from repro.core import telemetry
         telemetry.enable()
+    from repro import compile_cache
+    compile_cache.enable()
 
     def finish():
         if trace_path is not None:

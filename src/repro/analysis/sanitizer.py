@@ -415,9 +415,11 @@ def check_commit_invariants(manager, counts, moved_bytes,
 def _rows_bytes(rows):
     import numpy as np
 
+    from ..core.collections import _host_bytes
+
     if isinstance(rows, np.ndarray):
         return [rows.tobytes()]
-    return [np.asarray(r, np.uint8).tobytes() for r in rows]
+    return [_host_bytes(r).tobytes() for r in rows]
 
 
 def _sample_row_payload(payload, window_id):

@@ -1,16 +1,13 @@
-"""JAX version compatibility layer.
+"""One import point for the JAX API surface the repo uses (JAX 0.9).
 
-The repo targets the modern JAX API surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
-``pltpu.CompilerParams``) but must also run on the 0.4.x series, where
-those names live elsewhere or do not exist.  Every use site imports the
-symbol from here instead of guessing; the shim resolves once at import
-time so there is no per-call overhead.
+Use sites import these names from here, so a future JAX rename is one
+edit.  Each is the installed JAX's own function; only ``make_mesh``
+adds a default (Auto axis types) and ``tpu_compiler_params`` a short
+name.
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
 
@@ -21,90 +18,24 @@ __all__ = [
     "axis_size",
     "pcast_varying",
     "tpu_compiler_params",
-    "AXIS_TYPES_SUPPORTED",
 ]
 
-# ---------------------------------------------------------------------------
-# axis_size: jax.lax.axis_size is 0.5+; psum of the literal 1 over the
-# axis is the classic idiom and is evaluated statically at trace time.
-# ---------------------------------------------------------------------------
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:  # pragma: no cover - old JAX
-
-    def axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
-
-# ---------------------------------------------------------------------------
-# shard_map: top-level since jax 0.6; jax.experimental.shard_map before.
-# ---------------------------------------------------------------------------
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-else:  # pragma: no cover - exercised only on old JAX
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        # check_rep predates the pcast/pvary replication API; disable it
-        # so bodies written for the modern checker still trace.
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
-# ---------------------------------------------------------------------------
-# make_mesh: axis_types kwarg (and jax.sharding.AxisType) is 0.5+.
-# ---------------------------------------------------------------------------
-AXIS_TYPES_SUPPORTED = hasattr(jax.sharding, "AxisType")
+axis_size = jax.lax.axis_size
+shard_map = jax.shard_map
+set_mesh = jax.set_mesh
+pcast_varying = jax.lax.pcast
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if AXIS_TYPES_SUPPORTED:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
-# ---------------------------------------------------------------------------
-# set_mesh: ambient-mesh context manager (jax 0.5+/0.6+). The repo only
-# uses it around jit calls whose shardings are all explicit NamedShardings,
-# so a null context is a faithful fallback.
-# ---------------------------------------------------------------------------
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-elif hasattr(jax.sharding, "use_mesh"):  # pragma: no cover
-    set_mesh = jax.sharding.use_mesh
-else:  # pragma: no cover - old JAX
-
-    @contextlib.contextmanager
-    def set_mesh(mesh):
-        yield mesh
-
-
-# ---------------------------------------------------------------------------
-# pcast: replication-type casts exist only under the modern checker; with
-# check_rep=False (see shard_map above) the identity is equivalent.
-# ---------------------------------------------------------------------------
-if hasattr(jax.lax, "pcast"):
-    pcast_varying = jax.lax.pcast
-else:  # pragma: no cover - old JAX
-
-    def pcast_varying(x, axes, *, to="varying"):
-        del axes, to
-        return x
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params: CompilerParams (new) vs TPUCompilerParams.
-# ---------------------------------------------------------------------------
 def tpu_compiler_params(**kwargs):
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+
+    return pltpu.CompilerParams(**kwargs)

@@ -122,13 +122,19 @@ def _value_nbytes(x, _seen: set | None = None) -> int:
 #
 # A collection's payloads are Python structures (chunk arrays, key/value
 # pairs, pytrees of device buffers).  A *row codec* maps each payload to
-# fixed-width byte rows + a host-side manifest, so any transport — in
+# fixed-width rows + a host-side manifest, so any transport — in
 # particular the device ``all_to_all`` of ``core/transport.py`` — can
 # ship them without knowing the collection's internals, and the receiver
 # can rebuild a bit-identical payload.  Encoding is alias-aware: leaves
 # that alias one buffer encode (and ship) once, and decoding rebinds
 # them, so both the §5.3 byte accounting and the reconstructed aliasing
 # match the source exactly.
+#
+# A row is host uint8 bytes or device uint32 words (the TPU cannot hold
+# byte views without padding them many times over; see
+# ``kernels/reloc_codec.py``).  The two are one format: a byte row is
+# the little-endian view of its words, and every pytree leaf starts on
+# a word boundary.
 # ---------------------------------------------------------------------------
 def _dtype_token(dt) -> str:
     """Manifest-safe dtype spelling: ``.str`` (endianness-exact) when it
@@ -147,36 +153,59 @@ def _np_bytes(a) -> np.ndarray:
 
 def _np_from_bytes(row, dtype, shape) -> np.ndarray:
     nb = int(np.dtype(dtype).itemsize * np.prod(shape, dtype=np.int64))
-    buf = np.asarray(row, np.uint8)[:nb]
+    buf = _host_bytes(row)[:nb]
     return np.frombuffer(buf.tobytes(), dtype=dtype).reshape(shape).copy()
 
 
-def _jax_leaf_bytes(x):
-    """Device-side byte view of a jax leaf (no host transfer)."""
+def _host_bytes(row) -> np.ndarray:
+    """A wire row (host bytes, or uint32 words on host or device) as
+    host uint8 bytes — the little-endian view of the words."""
+    a = np.asarray(row)
+    if a.dtype != np.uint8:
+        a = np.ascontiguousarray(a).view(np.uint8)
+    return a
+
+
+def _row_words(row):
+    """A wire row as uint32 words: device words stay on device, host
+    bytes are viewed (zero-padded to a whole word)."""
     import jax
-    import jax.numpy as jnp
 
-    if x.dtype == jnp.bool_:
-        x = x.astype(jnp.uint8)
-    return jax.lax.bitcast_convert_type(x, jnp.uint8).reshape(-1)
+    if isinstance(row, jax.Array):
+        if row.dtype == np.uint32:
+            return row
+        import jax.numpy as jnp
+
+        u8 = jnp.asarray(row, jnp.uint8).reshape(-1)
+        u8 = jnp.pad(u8, (0, (-u8.shape[0]) % 4))
+        return jax.lax.bitcast_convert_type(u8.reshape(-1, 4), jnp.uint32)
+    a = _host_bytes(row).reshape(-1)
+    return np.pad(a, (0, (-a.shape[0]) % 4)).view(np.uint32)
 
 
-def _jax_leaf_from_bytes(row, dtype, shape):
-    """Inverse of :func:`_jax_leaf_bytes` — stays on device when ``row``
-    is a device buffer (the no-host-bounce decode path)."""
+def _padded(nb: int) -> int:
+    """Bytes a leaf of ``nb`` bytes occupies in a row (whole words)."""
+    return -(-int(nb) // 4) * 4
+
+
+def _jax_leaf_words(x):
+    """Device-side word view of a jax leaf (no host transfer)."""
+    from ..kernels.reloc_codec import to_words
+
+    return to_words(x.reshape(-1))
+
+
+def _leaf_from_words(words, dtype, shape):
+    """Inverse of :func:`_jax_leaf_words`; stays on device when
+    ``words`` is a device buffer, else decodes on host and puts the
+    leaf back on the device."""
     import jax
-    import jax.numpy as jnp
 
-    dt = np.dtype(dtype)
-    nb = int(dt.itemsize * np.prod(shape, dtype=np.int64))
-    u8 = jnp.asarray(row)[:nb].astype(jnp.uint8)
-    if dt == np.bool_:
-        return u8.reshape(shape).astype(jnp.bool_)
-    if dt.itemsize == 1:
-        return jax.lax.bitcast_convert_type(u8.reshape(shape),
-                                            jnp.dtype(dt))
-    return jax.lax.bitcast_convert_type(
-        u8.reshape(tuple(shape) + (dt.itemsize,)), jnp.dtype(dt))
+    from ..kernels.reloc_codec import from_words
+
+    n = int(np.prod(shape, dtype=np.int64))
+    leaf = from_words(words, np.dtype(dtype), n).reshape(shape)
+    return leaf if isinstance(leaf, jax.Array) else jax.device_put(leaf)
 
 
 def _encode_value(v) -> tuple[Any, tuple]:
@@ -222,23 +251,24 @@ def _encode_value(v) -> tuple[Any, tuple]:
         specs, pieces = [], []
         for x in uniq:
             if isinstance(x, jax.Array):
-                pieces.append(_jax_leaf_bytes(x))
+                pieces.append(_jax_leaf_words(x))
                 # dtype by *name*: ml_dtypes extensions (bfloat16, fp8)
                 # round-trip through np.dtype(name), their .str does not
                 specs.append(("jax", np.dtype(x.dtype).name,
                               tuple(x.shape), int(x.nbytes)))
             else:
                 a = np.asarray(x)
-                pieces.append(_np_bytes(a))
+                b = _np_bytes(a)
+                pieces.append(np.pad(b, (0, _padded(b.size) - b.size)))
                 specs.append(("nps" if isinstance(x, np.generic) else "np",
                               _dtype_token(a.dtype), a.shape,
                               int(a.nbytes)))
-        total = int(sum(s[3] for s in specs))
+        total = int(sum(_padded(s[3]) for s in specs))
         if any(isinstance(p, jax.Array) for p in pieces):
             import jax.numpy as jnp
             row = jnp.concatenate(
-                [jnp.asarray(p, jnp.uint8) for p in pieces]) if pieces \
-                else jnp.zeros((0,), jnp.uint8)
+                [p if isinstance(p, jax.Array) else jnp.asarray(p.view(
+                    np.uint32)) for p in pieces])
         else:
             row = np.concatenate(pieces) if pieces \
                 else np.zeros((0,), np.uint8)
@@ -265,20 +295,18 @@ def _decode_value(row, spec):
         import pickle
 
         _, nb = spec
-        return pickle.loads(np.asarray(row, np.uint8)[:nb].tobytes())
+        return pickle.loads(_host_bytes(row)[:nb].tobytes())
     _, treedef, specs, alias, _ = spec
+    words = _row_words(row)
     uniq, off = [], 0
-    host_row = None
     for lkind, dt, shape, nb in specs:
+        seg = words[off // 4:(off + _padded(nb)) // 4]
         if lkind == "jax":
-            uniq.append(_jax_leaf_from_bytes(row[off:off + nb], dt, shape))
+            uniq.append(_leaf_from_words(seg, dt, shape))
         else:
-            if host_row is None:
-                host_row = np.asarray(row, np.uint8)
-            leaf = _np_from_bytes(host_row[off:off + nb],
-                                  np.dtype(dt), shape)
+            leaf = _np_from_bytes(seg, np.dtype(dt), shape)
             uniq.append(leaf[()] if lkind == "nps" else leaf)
-        off += nb
+        off += _padded(nb)
     return jax.tree_util.tree_unflatten(treedef, [uniq[j] for j in alias])
 
 
@@ -706,9 +734,9 @@ class DistArray(DistCollection):
     def decode_rows(self, rows, manifest):
         """Inverse of :meth:`encode_rows`; ``rows`` may be wider than
         the encoded width (transport padding) and may live on device.
-        A device block on a fused codec backend decodes in-kernel
-        (trim + bitcast, ``kernels.reloc_codec.decode_rows``) and only
-        the typed result crosses to host."""
+        A device block of words (what the fused codec delivers) decodes
+        in-kernel (trim + reinterpret, ``kernels.reloc_codec.
+        decode_rows``) and only the typed result crosses to host."""
         _, r, dt, trail = manifest
         dtype = np.dtype(dt)
         m = r.size
@@ -718,17 +746,13 @@ class DistArray(DistCollection):
         if nb and not isinstance(rows, (np.ndarray, list)):
             import jax
 
-            if isinstance(rows, jax.Array):
+            if isinstance(rows, jax.Array) and rows.dtype == np.uint32:
                 from ..kernels import ops
-                from ..kernels.reloc_codec import jax_safe_dtype
 
-                if ops.resolve_backend() in ("pallas",
-                                             "pallas_interpret") \
-                        and jax_safe_dtype(dtype):
-                    out = ops.reloc_decode_rows(rows[:m], nbytes=nb,
-                                                dtype=dtype)
-                    return r, np.array(out).reshape((m,) + trail)
-        buf = np.asarray(rows, np.uint8)[:m, :nb]
+                out = ops.reloc_decode_rows(rows[:m], nbytes=nb,
+                                            dtype=dtype)
+                return r, np.array(out).reshape((m,) + trail)
+        buf = _host_bytes(rows)[:m, :nb]
         arr = np.frombuffer(np.ascontiguousarray(buf).tobytes(),
                             dtype=dtype).reshape((m,) + trail).copy()
         return r, arr

@@ -48,7 +48,7 @@ import numpy as np
 
 from ..analysis import sanitizer as _san
 from . import telemetry
-from .collections import PlaceGroup, lookup_collection
+from .collections import PlaceGroup, _host_bytes, lookup_collection
 from .transport import TransportStats, _account_exchange
 
 __all__ = [
@@ -511,6 +511,10 @@ def run_multiprocess(fn: Callable, nprocs: int, *args,
     processes (``spawn`` — no inherited JAX state) wired into a full
     pipe mesh; returns the per-rank results in rank order.
 
+    The ranks are a host control plane: every child starts with
+    ``JAX_PLATFORMS=cpu``, so a parent that holds the accelerator never
+    races its children for it (an accelerator belongs to one process).
+
     ``fn`` must be a module-level function (spawn pickles it by
     reference) and arguments/results must be picklable.  From a script,
     call this under ``if __name__ == "__main__":`` — spawn re-imports
@@ -588,19 +592,29 @@ def run_multiprocess(fn: Callable, nprocs: int, *args,
             ends[i][j] = ci
             ends[j][i] = cj
     procs, result_conns = [], []
-    for r in range(nprocs):
-        parent_end, child_end = ctx.Pipe(duplex=False)
-        p = ctx.Process(target=_worker_main,
-                        args=(fn, r, nprocs, ends[r], child_end,
-                              args, kwargs, collect_trace, sanitize,
-                              chaos_json, collective_timeout),
-                        daemon=True)
-        p.start()
-        child_end.close()
-        for c in ends[r].values():
-            c.close()   # parent's copies; the children own them now
-        procs.append(p)
-        result_conns.append(parent_end)
+    # spawn copies os.environ into each child at start(), and JAX reads
+    # JAX_PLATFORMS when the child imports it
+    prev_platforms = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    try:
+        for r in range(nprocs):
+            parent_end, child_end = ctx.Pipe(duplex=False)
+            p = ctx.Process(target=_worker_main,
+                            args=(fn, r, nprocs, ends[r], child_end,
+                                  args, kwargs, collect_trace, sanitize,
+                                  chaos_json, collective_timeout),
+                            daemon=True)
+            p.start()
+            child_end.close()
+            for c in ends[r].values():
+                c.close()   # parent's copies; the children own them now
+            procs.append(p)
+            result_conns.append(parent_end)
+    finally:
+        if prev_platforms is None:
+            del os.environ["JAX_PLATFORMS"]
+        else:
+            os.environ["JAX_PLATFORMS"] = prev_platforms
 
     results: list = [None] * nprocs
     # survivor tracebacks always fail the run; deaths (no result, EOF)
@@ -798,98 +812,92 @@ class DistributedTransport:
     def _device_wire_ready(self, backend) -> bool:
         if self.device_wire == "off" or backend.world_size <= 1:
             return False
-        try:
-            import jax
+        import jax
 
-            dist = getattr(jax, "distributed", None)
-            if dist is None or not getattr(dist, "is_initialized",
-                                           lambda: False)():
-                return False
-            return (jax.process_count() == backend.world_size
-                    and jax.device_count() >= backend.world_size
-                    and len(jax.local_devices()) >= 1)
-        except Exception:
+        if not jax.distributed.is_initialized():
             return False
+        return (jax.process_count() == backend.world_size
+                and jax.device_count() >= backend.world_size
+                and len(jax.local_devices()) >= 1)
 
-    def _exchange_rows_device(self, backend, outgoing: list) -> list | None:
+    def _exchange_rows_device(self, backend, outgoing: list) -> list:
         """Ship the wire entries' row bytes over a process-spanning
         device-mesh ``all_to_all`` (one device per process); manifests
         and shapes ride the control backend.  Returns the incoming
-        entry lists (same layout as the serialized wire) or ``None`` to
-        fall back.  Only taken under a real multi-controller
-        ``jax.distributed`` launch — CPU-only CI always falls back."""
-        try:
-            import jax
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import (Mesh, NamedSharding,
-                                      PartitionSpec as P)
+        entry lists (same layout as the serialized wire).  Only taken
+        under a real multi-controller ``jax.distributed`` launch; a
+        failure on that wire raises rather than falling back to the
+        pipes."""
+        import jax
+        from jax.sharding import (Mesh, NamedSharding,
+                                  PartitionSpec as P)
 
-            W = backend.world_size
-            # per-dest concatenated byte matrix + control entries that
-            # say how to split it back into payload rows
-            mats, ctrl = [], []
-            for dr in range(W):
-                blocks, centries = [], []
-                for gid, src, dest, rows, manifest in outgoing[dr]:
-                    if isinstance(rows, np.ndarray):
-                        blocks.append(rows)
-                        centries.append((gid, src, dest, manifest,
-                                         "mat", rows.shape))
-                    else:
-                        widths = [int(len(r)) for r in rows]
-                        wm = max(widths, default=0)
-                        m = np.zeros((len(rows), wm), np.uint8)
-                        for i, r in enumerate(rows):
-                            m[i, :widths[i]] = r
-                        blocks.append(m)
-                        centries.append((gid, src, dest, manifest,
-                                         "rows", widths))
-                mats.append(blocks)
-                ctrl.append(centries)
-            dims = [[(int(b.shape[0]), int(b.shape[1])) for b in blocks]
-                    for blocks in mats]
-            all_dims = backend.alltoall(dims)   # dims[me->dr] lands at dr
-            R = max((sum(r for r, _ in per) for per in dims), default=0)
-            C = max((c for per in dims for _, c in per), default=0)
-            # global padded extents (the collective is dense/static)
-            R = int(np.max(backend.allgather(R)))
-            C = int(np.max(backend.allgather(C)))
-            in_ctrl = backend.alltoall(ctrl)
-            if R == 0 or C == 0:
-                return [[(g, s, d, [] if k == "rows" else
-                          np.zeros((0, 0), np.uint8), mf)
-                         for g, s, d, mf, k, _ in in_ctrl[sr]]
-                        for sr in range(W)]
-            local = np.zeros((W, R, C), np.uint8)
-            for dr in range(W):
-                off = 0
-                for b in mats[dr]:
-                    local[dr, off:off + b.shape[0], :b.shape[1]] = b
-                    off += b.shape[0]
-            mesh = Mesh(np.asarray(jax.devices())[:W], ("proc",))
-            g = jax.make_array_from_single_device_arrays(
-                (W * W, R, C), NamedSharding(mesh, P("proc")),
-                [jax.device_put(local, jax.local_devices()[0])])
-            out = jax.jit(shard_map(
-                lambda x: jax.lax.all_to_all(x, "proc", 0, 0, tiled=True),
-                mesh=mesh, in_specs=P("proc"), out_specs=P("proc")))(g)
-            recv = np.asarray(out.addressable_shards[0].data)  # (W, R, C)
-            incoming = []
-            for sr in range(W):
-                entries, off = [], 0
-                for (gid, src, dest, manifest, kind, info), (m, c) in zip(
-                        in_ctrl[sr], all_dims[sr]):
-                    block = recv[sr, off:off + m, :c]
-                    off += m
-                    if kind == "mat":
-                        rows: Any = block
-                    else:
-                        rows = [block[i, :w] for i, w in enumerate(info)]
-                    entries.append((gid, src, dest, rows, manifest))
-                incoming.append(entries)
-            return incoming
-        except Exception:
-            return None   # fall back to the serialized pipe wire
+        from ..compat import shard_map
+
+        W = backend.world_size
+        # per-dest concatenated byte matrix + control entries that
+        # say how to split it back into payload rows
+        mats, ctrl = [], []
+        for dr in range(W):
+            blocks, centries = [], []
+            for gid, src, dest, rows, manifest in outgoing[dr]:
+                if isinstance(rows, np.ndarray):
+                    blocks.append(rows)
+                    centries.append((gid, src, dest, manifest,
+                                     "mat", rows.shape))
+                else:
+                    widths = [int(len(r)) for r in rows]
+                    wm = max(widths, default=0)
+                    m = np.zeros((len(rows), wm), np.uint8)
+                    for i, r in enumerate(rows):
+                        m[i, :widths[i]] = r
+                    blocks.append(m)
+                    centries.append((gid, src, dest, manifest,
+                                     "rows", widths))
+            mats.append(blocks)
+            ctrl.append(centries)
+        dims = [[(int(b.shape[0]), int(b.shape[1])) for b in blocks]
+                for blocks in mats]
+        all_dims = backend.alltoall(dims)   # dims[me->dr] lands at dr
+        R = max((sum(r for r, _ in per) for per in dims), default=0)
+        C = max((c for per in dims for _, c in per), default=0)
+        # global padded extents (the collective is dense/static)
+        R = int(np.max(backend.allgather(R)))
+        C = int(np.max(backend.allgather(C)))
+        in_ctrl = backend.alltoall(ctrl)
+        if R == 0 or C == 0:
+            return [[(g, s, d, [] if k == "rows" else
+                      np.zeros((0, 0), np.uint8), mf)
+                     for g, s, d, mf, k, _ in in_ctrl[sr]]
+                    for sr in range(W)]
+        local = np.zeros((W, R, C), np.uint8)
+        for dr in range(W):
+            off = 0
+            for b in mats[dr]:
+                local[dr, off:off + b.shape[0], :b.shape[1]] = b
+                off += b.shape[0]
+        mesh = Mesh(np.asarray(jax.devices())[:W], ("proc",))
+        g = jax.make_array_from_single_device_arrays(
+            (W * W, R, C), NamedSharding(mesh, P("proc")),
+            [jax.device_put(local, jax.local_devices()[0])])
+        out = jax.jit(shard_map(
+            lambda x: jax.lax.all_to_all(x, "proc", 0, 0, tiled=True),
+            mesh=mesh, in_specs=P("proc"), out_specs=P("proc")))(g)
+        recv = np.asarray(out.addressable_shards[0].data)  # (W, R, C)
+        incoming = []
+        for sr in range(W):
+            entries, off = [], 0
+            for (gid, src, dest, manifest, kind, info), (m, c) in zip(
+                    in_ctrl[sr], all_dims[sr]):
+                block = recv[sr, off:off + m, :c]
+                off += m
+                if kind == "mat":
+                    rows: Any = block
+                else:
+                    rows = [block[i, :w] for i, w in enumerate(info)]
+                entries.append((gid, src, dest, rows, manifest))
+            incoming.append(entries)
+        return incoming
 
     # -- the exchange ------------------------------------------------------
     def exchange(self, group, counts, payloads):
@@ -928,7 +936,7 @@ class DistributedTransport:
                 m, wmax = int(rows.shape[0]), int(rows.shape[1])
                 nb = int(rows.size)
             else:
-                wire_rows = [np.asarray(r, np.uint8) for r in rows]
+                wire_rows = [_host_bytes(r) for r in rows]
                 widths = [int(r.shape[0]) for r in wire_rows]
                 m = len(wire_rows)
                 wmax = max(widths, default=0)
@@ -944,10 +952,9 @@ class DistributedTransport:
             chaos = getattr(backend, "chaos", None)
             if chaos is not None:
                 outgoing = chaos.corrupt_outgoing(outgoing)
-            incoming = None
             if self._device_wire_ready(backend):
                 incoming = self._exchange_rows_device(backend, outgoing)
-            if incoming is None:
+            else:
                 incoming = backend.alltoall(outgoing)
             stats.exchanges += 1
             for sr in range(W):

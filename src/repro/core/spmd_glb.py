@@ -450,10 +450,10 @@ def run_device_steal(col, lifelines: dict[int, tuple[int, ...]],
       a real deployment would pay), so any dtype — float64 included —
       round-trips bit-exactly.
     * ``ship_rows=True`` — the *device* data plane: each row is encoded
-      to fixed-width bytes by the collection's row codec
-      (``DistArray.encode_rows``) and rides the loop's masked
-      ``all_to_all`` payload slot next to its id; the receiver decodes
-      bit-exactly (uint8 is dtype-safe without x64) and no host
+      to fixed-width 32-bit words by the collection's row codec and
+      rides the loop's masked ``all_to_all`` payload slot next to its
+      id; the receiver decodes bit-exactly (uint32 words are
+      dtype-safe without x64) and no host
       materialization happens.  Both planes run the identical jitted
       plan, so they produce *bit-identical* final collection state.
 
@@ -509,19 +509,36 @@ def _run_device_steal(col, lifelines, alive, *, steal_ratio, min_keep,
             raise ValueError("global indices exceed the int32 id payload")
         valid[i, :m] = True
         gids[i, :m] = idx
+    # on a fused codec backend the relocated rows stay on device: the
+    # collection's decode fast path trims + reinterprets them in-kernel
+    # and only the typed result crosses to host
+    fused_rows = False
     if ship_rows:
-        # codec-encoded byte rows ride the all_to_all payload slot (via
-        # the transport's donation probe: DistArray hands back zero-copy
-        # byte views instead of tobytes copies)
+        from ..kernels import ops
+        from ..kernels.reloc_codec import jax_safe_dtype, to_words
+
+        fused_rows = ops.resolve_backend() in ("pallas",
+                                               "pallas_interpret") \
+            and jax_safe_dtype(orig_dtype)
+        # codec rows ride the all_to_all payload slot as 32-bit words:
+        # the device word layout where the receiver decodes on device,
+        # the little-endian view of the host bytes where it decodes on
+        # host (via the transport's donation probe: DistArray hands back
+        # zero-copy byte views instead of tobytes copies)
         from .transport import _encode_rows
 
-        x = np.zeros((n, S, row_nbytes), np.uint8)
+        x = np.zeros((n, S, -(-row_nbytes // 4)), np.uint32)
         for i, (rows, idx) in enumerate(per_place):
             m = len(idx)
-            if m:
+            if not m:
+                continue
+            if fused_rows:
+                x[i, :m] = to_words(np.asarray(rows).reshape(m, -1))
+            else:
                 u8, _ = _encode_rows(
                     col, (LongRange(0, m), np.asarray(rows)))
-                x[i, :m] = u8
+                x[i, :m] = np.pad(u8, ((0, 0), (0, (-row_nbytes) % 4))) \
+                    .view(np.uint32)
     else:
         # the id column doubles as the payload for the host data plane
         x = np.where(valid, gids, 0).astype(np.int32)[:, :, None]
@@ -532,16 +549,6 @@ def _run_device_steal(col, lifelines, alive, *, steal_ratio, min_keep,
                   alive_mask.tobytes(), float(steal_ratio), int(min_keep),
                   int(idle_threshold), int(max_rounds))
     dev_out = fn(x, valid, gids)
-    # on a fused codec backend the relocated rows stay on device: the
-    # collection's decode fast path trims + bitcasts them in-kernel and
-    # only the typed result crosses to host
-    if ship_rows:
-        from ..kernels import ops
-
-        fused_rows = ops.resolve_backend() in ("pallas",
-                                               "pallas_interpret")
-    else:
-        fused_rows = False
     out = {k: (v if (fused_rows and k == "x") else np.asarray(v))
            for k, v in dev_out.items()}
 

@@ -312,8 +312,8 @@ class DeviceTransport:
     def _fused_exchange_fn(self, n: int, Sp: int, W: int):
         """The fused-codec collective: the kernel-packed send buffer is
         slotted per (src, dest) pair, so the all_to_all needs no mask
-        and no prefix bookkeeping — shard s's ``buf[d]`` block lands
-        verbatim at the receiver's ``recv[d][s]``."""
+        and no prefix bookkeeping — shard s's ``buf[d]`` word stream
+        lands verbatim at the receiver's ``recv[d][s]``."""
         key = ("fused", n, Sp, W)
         fn = self._fns.get(key)
         if fn is None:
@@ -366,8 +366,8 @@ class DeviceTransport:
                 raw_fn = getattr(col, "encode_rows_raw", None)
                 raw = raw_fn(payload) if raw_fn is not None else None
                 if raw is not None:
-                    # typed chunk matrix: the encode kernel bitcasts it
-                    # to wire bytes in-kernel — no host byte view at all
+                    # typed chunk matrix: the encode+pack call turns it
+                    # into wire words on device — no host byte view at all
                     mat, manifest = raw
                     m, k = int(mat.shape[0]), int(mat.shape[1])
                     nb = k * np.dtype(mat.dtype).itemsize
@@ -392,7 +392,8 @@ class DeviceTransport:
                      "dev": False}
             else:
                 rows = list(rows)
-                widths = [int(r.shape[0]) for r in rows]
+                widths = [int(r.size) * np.dtype(r.dtype).itemsize
+                          for r in rows]
                 e = {"pos": pos, "si": place_index[src],
                      "di": place_index[dest], "rows": rows,
                      "widths": widths, "m": len(rows),
@@ -521,7 +522,7 @@ class DeviceTransport:
                     blocks.append(blk)
                     continue
                 for r, w in zip(e["rows"], e["widths"]):
-                    r = jnp.asarray(r, jnp.uint8)
+                    r = _device_bytes(r)
                     if w < W:
                         r = jnp.concatenate(
                             [r, jnp.zeros((W - w,), jnp.uint8)])
@@ -534,27 +535,40 @@ class DeviceTransport:
     # -- the fused-kernel window path (backend "pallas"/"pallas_interpret")
     def _exchange_bucket_fused(self, n, W, bucket, payloads, delivered,
                                stats, backend):
-        """One fused-codec kernel + one unmasked ``all_to_all`` over the
-        entries of one row-width class.
+        """Fused-codec kernel + unmasked ``all_to_all`` over the entries
+        of one row-width class.
 
         The send buffer is slotted *per (src, dest) pair* — capacity is
         the pow2 of the busiest pair, every pair owns its own block — so
-        the whole encode → bitcast → permute → pad chain is a single
+        the whole encode → permute → pad chain is a single
         ``pallas_call``, the collective needs no mask, receiver blocks
         are contiguous slices, and fan-in can never overflow a shared
         prefix.  Delivered bytes are bit-identical to the composite
         path: entries pack in registration order within each pair, the
-        same order ``_exchange_bucket`` produces."""
+        same order ``_exchange_bucket`` produces.
+
+        A dense ``(pairs, slots, W)`` buffer of KV-sized rows can pass
+        the device's memory (4 replicas × 4 slots of 64 MiB rows is
+        4 GiB, twice over once the exchange copies it), so the pair
+        slots run in rounds of at most :data:`_EXCHANGE_BYTES` of
+        buffer each; a round's received blocks are sliced out before
+        the next round starts."""
         import jax
         import jax.numpy as jnp
 
         from ..kernels import ops
+        from ..kernels.reloc_codec import to_words
+        from .collections import _host_bytes, _row_words
 
         ship = np.zeros((n, n), np.int32)
         for e in bucket:
             ship[e["si"], e["di"]] += e["m"]
         Sp = 1 << (int(ship.max()) - 1).bit_length()
         pairs = n * n
+        cap = max(_EXCHANGE_BYTES // (pairs * W), 1)
+        Sp = min(Sp, 1 << (cap.bit_length() - 1))
+        rounds = -(-int(ship.max()) // Sp)
+        Sv = rounds * Sp                  # slots per pair over all rounds
 
         # slot assignment: each entry's rows land at [p0, p0+m) inside
         # its pair's block, accumulated in registration order
@@ -563,103 +577,126 @@ class DeviceTransport:
             e["p0"] = int(fill[e["si"], e["di"]])
             fill[e["si"], e["di"]] += e["m"]
 
-        wid_tab = np.zeros(pairs * Sp, np.int32)
+        wid_tab = np.zeros((pairs, Sv), np.int32)
+        src_tab = np.zeros((pairs, Sv), np.int32)   # row index / offset
         raw_keys = {(str(np.dtype(e["raw"].dtype)), int(e["raw"].shape[1]))
                     for e in bucket if "raw" in e}
         if len(raw_keys) == 1 and all("raw" in e for e in bucket):
-            # homogeneous typed bucket (the chunk-steal hot path): one
-            # fused encode+pack kernel straight off the concatenated
-            # chunk matrices — the bitcast happens in-kernel
-            idx_tab = np.zeros(pairs * Sp, np.int32)
+            # homogeneous typed bucket (the chunk-steal hot path): the
+            # encode+pack kernel reads straight off the concatenated
+            # chunk matrices
             mats, base = [], 0
             for e in bucket:
-                s0 = (e["si"] * n + e["di"]) * Sp + e["p0"]
-                idx_tab[s0:s0 + e["m"]] = np.arange(base, base + e["m"])
-                wid_tab[s0:s0 + e["m"]] = e["wmax"]
+                pr, s0 = e["si"] * n + e["di"], e["p0"]
+                src_tab[pr, s0:s0 + e["m"]] = np.arange(base, base + e["m"])
+                wid_tab[pr, s0:s0 + e["m"]] = e["wmax"]
                 mats.append(e["raw"])
                 base += e["m"]
             if any(isinstance(x, jax.Array) for x in mats):
                 src = jnp.concatenate([jnp.asarray(x) for x in mats])
             else:
                 src = np.concatenate(mats)
-            buf = ops.reloc_encode_pack(src, idx_tab, wid_tab,
-                                        pairs=pairs, slots=Sp, width=W,
-                                        impl=backend)
+            pack = ops.reloc_encode_pack
         else:
-            # mixed bucket: every entry contributes flat wire bytes to
-            # one arena; a single pack kernel gathers them into slots
-            off_tab = np.zeros(pairs * Sp, np.int32)
+            # mixed bucket: every entry contributes word rows to one
+            # arena; a single pack kernel gathers them into slots
             pieces, dev, base = [], False, 0
             for e in bucket:
-                s0 = (e["si"] * n + e["di"]) * Sp + e["p0"]
+                pr, s0 = e["si"] * n + e["di"], e["p0"]
                 if "rows" in e:
                     for j, (r, w) in enumerate(zip(e["rows"],
                                                    e["widths"])):
-                        off_tab[s0 + j] = base
-                        wid_tab[s0 + j] = w
-                        if isinstance(r, jax.Array):
-                            dev = True
-                            pieces.append(r)
-                        else:
-                            pieces.append(np.asarray(r, np.uint8))
-                        base += w
+                        r = _row_words(r)
+                        src_tab[pr, s0 + j] = base
+                        wid_tab[pr, s0 + j] = w
+                        dev = dev or isinstance(r, jax.Array)
+                        pieces.append(r)
+                        base += int(r.shape[0])
                     continue
-                bm = e["mat"] if "mat" in e else _byte_mat(e["raw"])
-                w, m = e["wmax"], e["m"]
-                off_tab[s0:s0 + m] = base + w * np.arange(m)
-                wid_tab[s0:s0 + m] = w
-                if isinstance(bm, jax.Array):
-                    dev = True
-                pieces.append(bm.reshape(-1))
-                base += m * w
-            # ≥ W trailing zeros: the kernel's fixed-width load of the
-            # last row must not read past the arena's end
-            pad = np.zeros(W, np.uint8)
+                if "mat" in e:
+                    wm = e["mat"]
+                    wm = np.pad(wm, ((0, 0), (0, (-wm.shape[1]) % 4)))
+                    wm = np.ascontiguousarray(wm).view(np.uint32)
+                else:
+                    wm = to_words(e["raw"])
+                m, wq = e["m"], int(wm.shape[1])
+                src_tab[pr, s0:s0 + m] = base + wq * np.arange(m)
+                wid_tab[pr, s0:s0 + m] = 4 * wq
+                dev = dev or isinstance(wm, jax.Array)
+                pieces.append(wm.reshape(-1))
+                base += m * wq
             if dev:
-                arena = jnp.concatenate(
-                    [jnp.asarray(p, jnp.uint8) for p in pieces]
-                    + [jnp.asarray(pad)])
+                src = jnp.concatenate([jnp.asarray(p) for p in pieces])
             else:
-                arena = np.concatenate(pieces + [pad])
-            buf = ops.reloc_pack_rows(arena, off_tab, wid_tab,
-                                      pairs=pairs, slots=Sp, width=W,
-                                      impl=backend)
+                src = np.concatenate(pieces)
+            del pieces
+            pack = ops.reloc_pack_rows
 
-        recv = self._fused_exchange_fn(n, Sp, W)(
-            buf.reshape(n, n, Sp, W))
-        stats.exchanges += 1
+        # recv[di, si] is exactly what si packed for di; each entry's
+        # rows are the contiguous slot range it claimed above.  Typed
+        # (raw) entries keep their block on device — the collection's
+        # decode fast path trims + reinterprets it in-kernel
+        blocks: dict[int, list] = {id(e): [] for e in bucket}
+        for k in range(rounds):
+            lo = k * Sp
+            buf = pack(src, src_tab[:, lo:lo + Sp].reshape(-1),
+                       wid_tab[:, lo:lo + Sp].reshape(-1), pairs=pairs,
+                       slots=Sp, width=W, impl=backend)
+            recv = self._fused_exchange_fn(n, Sp, W)(
+                buf.reshape((n, n) + buf.shape[1:]))
+            del buf
+            for e in bucket:
+                a = max(e["p0"], lo)
+                b = min(e["p0"] + e["m"], lo + Sp)
+                if a < b:
+                    blocks[id(e)].append(_slot_rows(
+                        recv[e["di"], e["si"]], a - lo, b - a, W))
+            del recv
+        stats.exchanges += rounds
         stats.width = max(stats.width, W)
         wire = int(ship.sum()) * W
         stats.wire_bytes += wire
         stats.pad_waste_bytes += wire - sum(e["nbytes"] for e in bucket)
 
-        # recv[di, si] is exactly what si packed for di; each entry's
-        # block is the contiguous slot slice it claimed above.  Typed
-        # (raw) entries keep their block on device — the collection's
-        # decode fast path trims + bitcasts it in-kernel
         for e in bucket:
-            block = recv[e["di"], e["si"], e["p0"]:e["p0"] + e["m"]]
+            parts = blocks[id(e)]
+            block = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
             if not (e["dev"] or "raw" in e):
-                block = np.asarray(block)
+                block = _host_bytes(block)
             rows = block if ("mat" in e or "raw" in e) \
                 else [block[i] for i in range(e["m"])]
-            col, src, dest, _ = payloads[e["pos"]]
+            col, src_, dest, _ = payloads[e["pos"]]
             delivered[e["pos"]] = (
-                col, src, dest, col.decode_rows(rows, e["manifest"]))
+                col, src_, dest, col.decode_rows(rows, e["manifest"]))
 
 
-def _byte_mat(mat):
-    """(m, k) typed matrix → (m, k*itemsize) uint8 wire view (device
-    bitcast for jax arrays, zero-copy view for contiguous numpy)."""
+def _slot_rows(stream, r0: int, m: int, width: int):
+    """Rows ``[r0, r0 + m)`` of one pair's ``(chunks, 1, 128)`` word
+    stream as an ``(m, width // 4)`` word block — only the chunks that
+    hold them are touched."""
+    wq = width // 4
+    a, b = r0 * wq, (r0 + m) * wq
+    c0, c1 = a // 128, -(-b // 128)
+    words = stream[c0:c1].reshape(-1)
+    return words[a - c0 * 128:b - c0 * 128].reshape(m, wq)
+
+
+def _device_bytes(row):
+    """A device row (uint32 words or bytes) as a 1-D device uint8 view —
+    the composite path's byte buffers (an XLA bitcast; on a TPU this
+    view is padded many times over, which is why the fused path stays
+    in words)."""
     import jax
+    import jax.numpy as jnp
 
-    m = int(mat.shape[0])
-    nb = int(mat.shape[1]) * np.dtype(mat.dtype).itemsize
-    if isinstance(mat, jax.Array):
-        import jax.numpy as jnp
+    row = jnp.asarray(row)
+    if row.dtype == jnp.uint8:
+        return row
+    return jax.lax.bitcast_convert_type(row, jnp.uint8).reshape(-1)
 
-        return jax.lax.bitcast_convert_type(mat, jnp.uint8).reshape(m, nb)
-    return np.ascontiguousarray(mat).view(np.uint8).reshape(m, nb)
+
+# device bytes one fused exchange round may hold in its send buffer
+_EXCHANGE_BYTES = 1 << 30
 
 
 def make_transport(spec: Any) -> RelocationTransport:

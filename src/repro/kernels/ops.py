@@ -102,8 +102,9 @@ def rg_lru_scan(x, a, h0=None, *, impl: str | None = None, **block_kw):
 
 def reloc_encode_pack(mat, idx, widths, *, pairs, slots, width,
                       impl: str | None = None):
-    """Fused encode+pack: collection chunk rows → all_to_all buffer
-    (bitcast, destination permutation, padding in one kernel)."""
+    """Encode+pack: collection chunk rows → the ``(pairs, chunks, 1,
+    128)`` uint32 send buffer (word view, destination permutation and
+    padding in one call)."""
     b = _resolve(impl)
     if b in ("xla", "xla_naive"):
         return ref.reloc_encode_pack_ref(mat, idx, widths, pairs=pairs,
@@ -113,22 +114,22 @@ def reloc_encode_pack(mat, idx, widths, *, pairs, slots, width,
                            interpret=(b == "pallas_interpret"))
 
 
-def reloc_pack_rows(flat_src, offsets, widths, *, pairs, slots, width,
+def reloc_pack_rows(words, offsets, widths, *, pairs, slots, width,
                     impl: str | None = None):
-    """Pack pre-encoded ragged byte rows into the all_to_all buffer."""
+    """Pack pre-encoded word rows of a flat arena into the send buffer."""
     b = _resolve(impl)
     if b in ("xla", "xla_naive"):
-        return ref.reloc_pack_rows_ref(flat_src, offsets, widths,
+        return ref.reloc_pack_rows_ref(words, offsets, widths,
                                        pairs=pairs, slots=slots,
                                        width=width)
-    return _rc.pack_rows(flat_src, offsets, widths, pairs=pairs,
+    return _rc.pack_rows(words, offsets, widths, pairs=pairs,
                          slots=slots, width=width,
                          interpret=(b == "pallas_interpret"))
 
 
 def reloc_decode_rows(rows, *, nbytes, dtype, impl: str | None = None):
-    """Fused unpack+decode: delivered wire rows → typed chunk rows
-    (class padding trimmed, manifest dtype bitcast in-kernel)."""
+    """Unpack+decode: delivered word rows → typed chunk rows (class
+    padding trimmed, manifest dtype restored)."""
     b = _resolve(impl)
     if b in ("xla", "xla_naive"):
         return ref.reloc_decode_rows_ref(rows, nbytes=nbytes, dtype=dtype)

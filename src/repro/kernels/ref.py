@@ -188,49 +188,47 @@ def mlstm_ref(q, k, v, i_gate, f_gate, c0=None, n0=None, m0=None):
 # ---------------------------------------------------------------------------
 # relocation codec oracles (reloc_codec.py)
 # ---------------------------------------------------------------------------
-def _u8_rows(mat):
-    """(m, k) any-dtype rows → (m, k*itemsize) uint8 wire rows."""
-    m, k = mat.shape
-    isz = jnp.dtype(mat.dtype).itemsize
-    u8 = jax.lax.bitcast_convert_type(mat, jnp.uint8)
-    return u8.reshape(m, k * isz) if isz > 1 else u8
+def _wire(rows, pairs, slots, width):
+    """(pairs*slots, W/4) word rows → the ``(pairs, chunks, 1, 128)``
+    send-buffer layout."""
+    per_pair = rows.reshape(pairs, slots * (width // 4))
+    n = max(slots * width // 512, 1) * 128
+    per_pair = jnp.pad(per_pair, ((0, 0), (0, n - per_pair.shape[1])))
+    return per_pair.reshape(pairs, -1, 1, 128)
+
+
+def _keep(widths, width):
+    live = (jnp.asarray(widths, jnp.int32)[:, None] + 3) // 4
+    return jnp.arange(width // 4, dtype=jnp.int32)[None, :] < live
 
 
 def reloc_encode_pack_ref(mat, idx, widths, *, pairs, slots, width):
     """Oracle for :func:`repro.kernels.reloc_codec.encode_pack`."""
-    mat = jnp.asarray(mat)
-    u8 = _u8_rows(mat)
-    nb = int(u8.shape[1])
-    if width > nb:
-        u8 = jnp.pad(u8, ((0, 0), (0, width - nb)))
-    idx = jnp.clip(jnp.asarray(idx, jnp.int32), 0, mat.shape[0] - 1)
-    rows = u8[idx]                                   # (pairs*slots, width)
-    keep = jnp.arange(width, dtype=jnp.int32)[None, :] \
-        < jnp.asarray(widths, jnp.int32)[:, None]
-    return jnp.where(keep, rows, 0).reshape(pairs, slots, width)
+    from .reloc_codec import to_words
+
+    words = to_words(jnp.asarray(mat))
+    words = jnp.pad(words, ((0, 0), (0, width // 4 - words.shape[1])))
+    idx = jnp.clip(jnp.asarray(idx, jnp.int32), 0, words.shape[0] - 1)
+    rows = jnp.where(_keep(widths, width), words[idx], jnp.uint32(0))
+    return _wire(rows, pairs, slots, width)
 
 
-def reloc_pack_rows_ref(flat_src, offsets, widths, *, pairs, slots, width):
+def reloc_pack_rows_ref(words, offsets, widths, *, pairs, slots, width):
     """Oracle for :func:`repro.kernels.reloc_codec.pack_rows`."""
-    flat_src = jnp.asarray(flat_src, jnp.uint8)
-    span = jnp.arange(width, dtype=jnp.int32)
+    words = jnp.asarray(words, jnp.uint32)
+    span = jnp.arange(width // 4, dtype=jnp.int32)
     pos = jnp.asarray(offsets, jnp.int32)[:, None] + span[None, :]
-    rows = flat_src[jnp.clip(pos, 0, flat_src.shape[0] - 1)]
-    keep = span[None, :] < jnp.asarray(widths, jnp.int32)[:, None]
-    return jnp.where(keep, rows, 0).reshape(pairs, slots, width)
+    rows = words[jnp.clip(pos, 0, words.shape[0] - 1)]
+    rows = jnp.where(_keep(widths, width), rows, jnp.uint32(0))
+    return _wire(rows, pairs, slots, width)
 
 
 def reloc_decode_rows_ref(rows, *, nbytes, dtype):
     """Oracle for :func:`repro.kernels.reloc_codec.decode_rows`."""
     import numpy as np
 
-    rows = jnp.asarray(rows)
-    m = int(rows.shape[0])
+    from .reloc_codec import from_words
+
     dt = np.dtype(dtype)
-    k = nbytes // dt.itemsize
-    u8 = rows[:, :nbytes].astype(jnp.uint8)
-    if dt.itemsize == 1:
-        return jax.lax.bitcast_convert_type(u8.reshape(m, k),
-                                            jnp.dtype(dt))
-    return jax.lax.bitcast_convert_type(u8.reshape(m, k, dt.itemsize),
-                                        jnp.dtype(dt))
+    return from_words(jnp.asarray(rows, jnp.uint32), dt,
+                      nbytes // dt.itemsize)

@@ -7,6 +7,8 @@ same window scenario over a 4-place group with ``DistributedTransport``
 and gather their final state; the tests then assert it is bit-identical
 to the same scenario run in-process over ``HostTransport``.
 """
+import os
+
 import numpy as np
 import pytest
 
@@ -166,6 +168,12 @@ def _backend_ops_worker(backend, base):
     return {"a2a": a2a, "red": red.tolist(), "bc": bc}
 
 
+def _platform_worker(backend):
+    import jax
+
+    return os.environ.get("JAX_PLATFORMS"), jax.default_backend()
+
+
 def _failing_worker(backend):
     if backend.rank == 1:
         raise RuntimeError("rank 1 exploded")
@@ -184,6 +192,14 @@ class TestLauncher:
     def test_worker_exception_reraises_with_traceback(self):
         with pytest.raises(RuntimeError, match="rank 1 exploded"):
             run_multiprocess(_failing_worker, 2)
+
+    def test_children_run_on_cpu(self, monkeypatch):
+        # ranks are a host control plane: a parent holding the
+        # accelerator must never race its children for it
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+        out = run_multiprocess(_platform_worker, 2)
+        assert out == [("cpu", "cpu"), ("cpu", "cpu")]
+        assert os.environ["JAX_PLATFORMS"] == "tpu"   # parent untouched
 
     def test_nprocs_1_runs_inline_on_local_backend(self):
         out = run_multiprocess(_backend_ops_worker, 1, 5)

@@ -17,8 +17,9 @@ from repro.core import (CollectiveMoveManager, DeviceTransport, DistArray,
                         PlaceGroup)
 from repro.core import telemetry
 from repro.kernels import ops, ref
-from repro.kernels.reloc_codec import (LRUCache, jax_safe_dtype,
-                                       kernel_cache_info)
+from repro.kernels.reloc_codec import (LRUCache, from_words,
+                                       jax_safe_dtype, kernel_cache_info,
+                                       to_words, wire_rows)
 
 
 @contextlib.contextmanager
@@ -62,20 +63,28 @@ class TestKernelParity:
         want = np.asarray(ref.reloc_encode_pack_ref(
             mat, idx, wid, pairs=4, slots=2, width=W))
         assert np.array_equal(got, want)
-        # and the oracle itself equals the host tobytes wire format
-        u8 = np.frombuffer(mat.tobytes(), np.uint8).reshape(7, nb)
-        assert np.array_equal(want[0, 0, :nb], u8[3])
-        assert np.array_equal(want[3, 1, :nb], u8[2])
-        assert not want[0, 2:].any() if want.shape[1] > 2 else True
+        # live slots hold their row's words; 4-byte rows are the host
+        # bytes, narrower ones the plane layout — both invert exactly
+        rows = wire_rows(want, 2, W)
+        assert np.array_equal(from_words(rows[0, 0], dt, 3), mat[3])
+        assert np.array_equal(from_words(rows[3, 1], dt, 3), mat[2])
+        if dt.itemsize == 4:
+            u8 = np.frombuffer(mat.tobytes(), np.uint8).reshape(7, nb)
+            assert np.array_equal(rows[0, 0].view(np.uint8)[:nb], u8[3])
+        assert not rows[1].any()            # both of pair 1's slots empty
+        assert not rows[0, 0, -(-nb // 4):].any()   # class padding zero
 
     def test_pack_rows_ragged_matches_ref(self):
         rng = np.random.default_rng(3)
         widths = [5, 12, 1, 8]
         rows = [rng.integers(0, 256, w).astype(np.uint8) for w in widths]
-        flat = np.concatenate(rows + [np.zeros(16, np.uint8)])
+        # each byte row occupies whole words of the arena
+        words = [np.pad(r, (0, (-w) % 4)).view(np.uint32)
+                 for r, w in zip(rows, widths)]
+        flat = np.concatenate(words)
         offs = np.zeros(8, np.int32)
         wids = np.zeros(8, np.int32)
-        offs[:4] = np.cumsum([0] + widths[:-1])
+        offs[:4] = np.cumsum([0] + [len(w) for w in words[:-1]])
         wids[:4] = widths
         got = np.asarray(ops.reloc_pack_rows(
             flat, offs, wids, pairs=4, slots=2, width=16,
@@ -83,7 +92,9 @@ class TestKernelParity:
         want = np.asarray(ref.reloc_pack_rows_ref(
             flat, offs, wids, pairs=4, slots=2, width=16))
         assert np.array_equal(got, want)
-        assert np.array_equal(got[0, 0, :5], rows[0])
+        got = wire_rows(got, 2, 16)
+        assert np.array_equal(got[0, 0].view(np.uint8)[:5], rows[0])
+        assert not got[0, 0].view(np.uint8)[8:].any()   # past the row
         assert not got[2:].any()   # empty pairs are zero capacity
 
     @pytest.mark.parametrize("dtype", ["float32", "int32", "int8"])
@@ -92,8 +103,8 @@ class TestKernelParity:
         rng = np.random.default_rng(11)
         src = (rng.integers(-50, 50, (5, 4))).astype(dt)
         nb = 4 * dt.itemsize
-        wire = np.frombuffer(src.tobytes(), np.uint8).reshape(5, nb)
-        padded = np.pad(wire, ((0, 0), (0, 32 - nb)))
+        wire = to_words(src)
+        padded = np.pad(wire, ((0, 0), (0, 8 - wire.shape[1])))
         for impl in ("pallas_interpret", "xla"):
             back = np.asarray(ops.reloc_decode_rows(
                 padded, nbytes=nb, dtype=dt, impl=impl))
@@ -127,7 +138,7 @@ def test_property_kernel_roundtrip(m, k, dt, extra):
     buf = ops.reloc_encode_pack(mat, idx, wid, pairs=1, slots=slots,
                                 width=W, impl="pallas_interpret")
     back = np.asarray(ops.reloc_decode_rows(
-        buf[0, :m], nbytes=nb, dtype=np.dtype(dtype),
+        wire_rows(buf, slots, W)[0, :m], nbytes=nb, dtype=np.dtype(dtype),
         impl="pallas_interpret"))
     assert back.dtype == mat.dtype
     assert np.array_equal(back.view(np.uint8), mat.view(np.uint8))
@@ -149,6 +160,19 @@ class TestFusedWindowParity:
             composite = _drive_windows(DeviceTransport(), 2)
         host = _drive_windows(HostTransport(), 2)
         assert fused == composite == host
+
+    def test_bounded_exchange_rounds_parity(self, monkeypatch):
+        # a send buffer over the device-memory bound runs as several
+        # exchange rounds; entries straddling rounds reassemble exactly
+        from test_transport import _drive_windows
+        from repro.core import transport
+
+        monkeypatch.setattr(transport, "_EXCHANGE_BYTES", 256)
+        t = DeviceTransport()
+        with backend("pallas_interpret"):
+            split = _drive_windows(t, 2)
+        assert split == _drive_windows(HostTransport(), 2)
+        assert t.lifetime.exchanges > 4
 
     @pytest.mark.parametrize("dtype", [np.float32, np.int32, np.float64])
     def test_chunk_moves_parity_across_dtypes(self, dtype, fused):
@@ -367,9 +391,9 @@ class TestLRUCaches:
     def test_kernel_cache_is_lru(self):
         info = kernel_cache_info()
         assert info["cap"] >= 1
-        ops.reloc_decode_rows(np.zeros((2, 8), np.uint8), nbytes=4,
+        ops.reloc_decode_rows(np.zeros((2, 2), np.uint32), nbytes=4,
                               dtype=np.float32, impl="pallas_interpret")
-        ops.reloc_decode_rows(np.zeros((2, 8), np.uint8), nbytes=4,
+        ops.reloc_decode_rows(np.zeros((2, 2), np.uint32), nbytes=4,
                               dtype=np.float32, impl="pallas_interpret")
         info2 = kernel_cache_info()
         assert info2["hits"] > info["hits"]

@@ -126,8 +126,9 @@ class TestRowCodecs:
         kv = SeqKV({"k": page, "v": page},
                    jax.device_put(np.zeros((1, 1), np.int32)))
         row, spec = _encode_value(kv)
-        # the shared page crosses the wire once
-        assert int(row.shape[0]) == page.nbytes + 4
+        # the shared page crosses the wire once (a device row is words)
+        assert row.dtype == np.uint32
+        assert int(row.shape[0]) * 4 == page.nbytes + 4
         back = _decode_value(row, spec)
         assert back.state["k"] is back.state["v"]
 
