@@ -24,6 +24,17 @@ layer), this module is the one place every subsystem reports to:
   via their ``as_dict``/``publish`` methods rather than growing more
   parallel bespoke structs.
 
+* **The profiler's clock** — while telemetry is enabled every
+  :func:`span` also opens a ``jax.profiler.TraceAnnotation`` of the same
+  name, so a running ``jax.profiler`` trace holds the program's spans
+  on its host plane beside the device's work.  The tracer's clock
+  (:func:`now_us`) reads the same wall clock as the profiler's
+  timestamps.  :func:`complete` spans are timed after the fact and
+  reach only the ring buffer.  JAX's own trace, lower and compile
+  times arrive as complete spans ``jax.trace``, ``jax.lower`` and
+  ``jax.compile`` through one ``jax.monitoring`` listener, registered
+  the first time telemetry is enabled.
+
 * **Export + aggregation** — :func:`chrome_trace` /
   :func:`write_chrome_trace` dump a Perfetto-loadable timeline (one
   track per rank/place); :func:`allgather_spans` rides any process
@@ -36,7 +47,8 @@ Two hard requirements shape the implementation:
 * **Zero-cost-when-disabled.**  The module-level ``_ENABLED`` flag is
   checked before *any* attribute formatting or record allocation;
   disabled ``span()`` returns the shared :data:`NULL_SPAN` singleton
-  and ``event``/``observe``/``inc``/``gauge`` return immediately.
+  (no profiler annotation is built) and
+  ``event``/``observe``/``inc``/``gauge`` return immediately.
   Instrumented hot paths stay on by default in benchmarks.
 
 * **Bounded memory.**  The span buffer is a fixed-capacity ring: when
@@ -87,7 +99,9 @@ _ENABLED = False
 
 # wall-clock anchor: perf_counter is monotonic but per-process; adding
 # the anchor puts every rank's timestamps on the (roughly) shared
-# wall clock so merged cross-rank timelines line up in Perfetto
+# wall clock so merged cross-rank timelines line up in Perfetto, and on
+# the clock of jax.profiler's host events (tests/test_profiler_spans.py
+# holds the two within a millisecond)
 _ANCHOR = time.time() - time.perf_counter()
 
 
@@ -169,10 +183,28 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class Span:
-    """One open span; records itself into its tracer on ``__exit__``."""
+# jax.profiler, imported on the first span opened while enabled: a
+# disabled process never imports it from here
+_PROFILER = None
 
-    __slots__ = ("_tracer", "name", "attrs", "t0", "t1")
+
+def _annotation(name: str):
+    global _PROFILER
+    if _PROFILER is None:
+        import jax.profiler
+
+        _PROFILER = jax.profiler
+    return _PROFILER.TraceAnnotation(name)
+
+
+class Span:
+    """One open span; records itself into its tracer on ``__exit__``.
+
+    While it is open a ``jax.profiler.TraceAnnotation`` of the same name
+    is open on the same thread, so a profiler trace taken meanwhile
+    shows the span on its host plane."""
+
+    __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -180,6 +212,7 @@ class Span:
         self.attrs = attrs
         self.t0 = 0.0
         self.t1 = 0.0
+        self._ann = None
 
     def __bool__(self):
         return True
@@ -189,11 +222,14 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, etype, evalue, tb):
         self.t1 = _now_us()
+        self._ann.__exit__(etype, evalue, tb)
         if etype is not None:
             self.attrs["error"] = etype.__name__
         self._tracer._record(self.name, "X", self.t0,
@@ -249,7 +285,9 @@ class Tracer:
     def complete(self, name: str, t0_us: float, t1_us: float,
                  **attrs) -> None:
         """Record an already-timed span (begin/end measured elsewhere —
-        e.g. a relocation window whose phases ran on three threads)."""
+        e.g. a relocation window whose phases ran on three threads).
+        It reaches the ring buffer only: a profiler trace cannot take a
+        span after its end."""
         if not _ENABLED:
             return
         self._record(name, "X", t0_us, t1_us - t0_us, attrs)
@@ -530,12 +568,46 @@ def enabled() -> bool:
     return _ENABLED
 
 
+# JAX's compile-path duration events, recorded as complete spans
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_COMPILE_LISTENING = False
+
+
+def _on_compile_event(event: str, duration_secs: float, **_) -> None:
+    """The ``jax.monitoring`` listener: a trace, lower or compile that
+    just ended on this thread becomes a complete span ending now."""
+    if not _ENABLED:
+        return
+    name = _COMPILE_EVENTS.get(event)
+    if name is not None:
+        now = _now_us()
+        _TRACER.complete(name, now - duration_secs * 1e6, now)
+
+
+def _listen_to_compiles() -> None:
+    global _COMPILE_LISTENING
+    if not _COMPILE_LISTENING:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _COMPILE_LISTENING = True
+
+
 def enable(*, rank: int | None = None,
            capacity: int | None = None) -> Tracer:
     """Turn recording on.  ``rank`` tags every subsequent record's
     ``pid`` (multi-process workers pass their backend rank);
-    ``capacity`` resizes (and clears) the ring buffer."""
+    ``capacity`` resizes (and clears) the ring buffer.  The first call
+    registers the compile listener (``jax.trace``/``jax.lower``/
+    ``jax.compile`` spans); it stays registered and is idle while
+    telemetry is disabled."""
     global _ENABLED, _TRACER
+    _listen_to_compiles()
     if capacity is not None and capacity != _TRACER.capacity:
         replacement = Tracer(capacity=capacity, rank=_TRACER.rank)
         # listeners (e.g. the relocation sanitizer) survive a resize
@@ -586,8 +658,9 @@ def complete(name: str, t0_us: float, t1_us: float, **attrs) -> None:
 
 
 def now_us() -> float:
-    """The tracer's clock (wall-anchored microseconds) — for callers
-    assembling :func:`complete` spans from their own stamps."""
+    """The tracer's clock (wall-anchored microseconds, the profiler's
+    wall clock) — for callers assembling :func:`complete` spans from
+    their own stamps."""
     return _now_us()
 
 

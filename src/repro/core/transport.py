@@ -71,6 +71,10 @@ class TransportStats:
     #                          actually shipped — the pow2 _width_class
     #                          padding overhead, the number the fused
     #                          codec trajectory is judged against
+    buffer_bytes: int = 0    # bytes of the dense send buffers the
+    #                          exchanges allocated, empty slots included
+    #                          (fused: pairs × slots × W per round;
+    #                          masked: places × capacity × W)
     width: int = 0           # widest padded row-width class exchanged
     exchanges: int = 0       # jitted all_to_all dispatches (one per
     #                          row-width class in the window)
@@ -88,6 +92,7 @@ class TransportStats:
         self.row_bytes += other.row_bytes
         self.wire_bytes += other.wire_bytes
         self.pad_waste_bytes += other.pad_waste_bytes
+        self.buffer_bytes += other.buffer_bytes
         self.exchanges += other.exchanges
         self.width = max(self.width, other.width)
         if other.codec_backend:
@@ -105,6 +110,7 @@ class TransportStats:
             f"{prefix}row_bytes": self.row_bytes,
             f"{prefix}wire_bytes": self.wire_bytes,
             f"{prefix}pad_waste_bytes": self.pad_waste_bytes,
+            f"{prefix}buffer_bytes": self.buffer_bytes,
             f"{prefix}width": self.width,
             f"{prefix}exchanges": self.exchanges,
             f"{prefix}codec_backend": self.codec_backend,
@@ -125,7 +131,7 @@ class TransportStats:
             p = f"transport.{self.kind}."
             names = tuple(p + f for f in (
                 "payloads", "local", "rows", "row_bytes", "wire_bytes",
-                "pad_waste_bytes", "exchanges", "width"))
+                "pad_waste_bytes", "buffer_bytes", "exchanges", "width"))
             _PUBLISH_NAMES[self.kind] = names
         reg.counter(names[0]).set(self.payloads)
         reg.counter(names[1]).set(self.local)
@@ -133,8 +139,9 @@ class TransportStats:
         reg.counter(names[3]).set(self.row_bytes)
         reg.counter(names[4]).set(self.wire_bytes)
         reg.counter(names[5]).set(self.pad_waste_bytes)
-        reg.counter(names[6]).set(self.exchanges)
-        reg.gauge(names[7]).set(self.width)
+        reg.counter(names[6]).set(self.buffer_bytes)
+        reg.counter(names[7]).set(self.exchanges)
+        reg.gauge(names[8]).set(self.width)
 
 
 # metric-name tuples per transport kind, built once (publish is invoked
@@ -145,22 +152,20 @@ _PUBLISH_NAMES: dict = {}
 def _account_exchange(transport, stats: TransportStats, sp) -> None:
     """Shared post-exchange bookkeeping for every backend: fold the
     window stats into the transport's lifetime totals (under its lock),
-    stamp the open ``transport.exchange`` span, register the lifetime
-    stats as a registry publisher, and feed the wire histograms.  One
+    stamp this exchange's numbers on the open ``transport.exchange``
+    span, and register the lifetime stats as a registry publisher.  One
     implementation — the Device and Distributed backends used to each
     hand-roll the lifetime accumulation."""
     with transport._lifetime_lock:
         transport.lifetime.merge(stats)
     if sp:
         sp.set(payloads=stats.payloads, local=stats.local,
-               rows=stats.rows, wire_bytes=stats.wire_bytes,
-               width=stats.width, exchanges=stats.exchanges)
-    if telemetry.enabled():
+               rows=stats.rows, row_bytes=stats.row_bytes,
+               wire_bytes=stats.wire_bytes,
+               buffer_bytes=stats.buffer_bytes, width=stats.width,
+               exchanges=stats.exchanges)
         telemetry.metrics().add_publisher(
             id(transport), transport.lifetime.publish)
-        telemetry.observe("transport.exchange_wire_bytes",
-                          stats.wire_bytes)
-        telemetry.observe("transport.exchange_rows", stats.rows)
 
 
 # per-collection-type capability probe for the codec donation fast path
@@ -292,7 +297,7 @@ class DeviceTransport:
 
             from .spmd_glb import _ship_hop
 
-            def per_shard(buf, ship):
+            def transport_masked_all_to_all(buf, ship):
                 # prefix invariant: each shard's outgoing rows occupy
                 # slots [0, sum(ship[me])) grouped by destination — the
                 # same layout _ship_hop's cumsum gathers assume, so the
@@ -304,8 +309,9 @@ class DeviceTransport:
                                      axis_name="transport")
                 return nx
 
-            fn = jax.jit(jax.vmap(per_shard, axis_name="transport",
-                                  in_axes=(0, None)))
+            # the function's name is the program's name in a profile
+            fn = jax.jit(jax.vmap(transport_masked_all_to_all,
+                                  axis_name="transport", in_axes=(0, None)))
             self._fns.put(key, fn)
         return fn
 
@@ -319,11 +325,12 @@ class DeviceTransport:
         if fn is None:
             import jax
 
-            def per_shard(buf):
+            def transport_all_to_all(buf):
                 return jax.lax.all_to_all(buf, "transport", 0, 0,
                                           tiled=False)
 
-            fn = jax.jit(jax.vmap(per_shard, axis_name="transport"))
+            fn = jax.jit(jax.vmap(transport_all_to_all,
+                                  axis_name="transport"))
             self._fns.put(key, fn)
         return fn
 
@@ -356,69 +363,72 @@ class DeviceTransport:
         fused = backend in ("pallas", "pallas_interpret")
         stats = TransportStats(kind="device", codec_backend=backend)
 
-        # encode off-place payloads; self-moves bypass the wire verbatim
-        entries: dict[int, dict] = {}   # payload position -> wire entry
-        for pos, (col, src, dest, payload) in enumerate(payloads):
-            if src == dest:
-                stats.local += 1
-                continue
-            if fused:
-                raw_fn = getattr(col, "encode_rows_raw", None)
-                raw = raw_fn(payload) if raw_fn is not None else None
-                if raw is not None:
-                    # typed chunk matrix: the encode+pack call turns it
-                    # into wire words on device — no host byte view at all
-                    mat, manifest = raw
-                    m, k = int(mat.shape[0]), int(mat.shape[1])
-                    nb = k * np.dtype(mat.dtype).itemsize
-                    entries[pos] = {
-                        "pos": pos, "si": place_index[src],
-                        "di": place_index[dest], "raw": mat, "m": m,
-                        "wmax": nb, "nbytes": m * nb,
-                        "manifest": manifest,
-                        "dev": isinstance(mat, jax.Array)}
-                    stats.payloads += 1
-                    stats.rows += m
-                    stats.row_bytes += m * nb
+        # staging: encode each payload and bucket it by width class
+        with telemetry.span("transport.stage"):
+            # encode off-place payloads; self-moves bypass the wire verbatim
+            entries: dict[int, dict] = {}   # payload position -> wire entry
+            for pos, (col, src, dest, payload) in enumerate(payloads):
+                if src == dest:
+                    stats.local += 1
                     continue
-            rows, manifest = _encode_rows(col, payload)
-            if isinstance(rows, np.ndarray) and rows.ndim == 2:
-                # chunk payloads stay one (m, w) matrix end to end: the
-                # pack is a single block copy, never m row assignments
-                e = {"pos": pos, "si": place_index[src],
-                     "di": place_index[dest], "mat": rows,
-                     "m": int(rows.shape[0]), "wmax": int(rows.shape[1]),
-                     "nbytes": int(rows.size), "manifest": manifest,
-                     "dev": False}
-            else:
-                rows = list(rows)
-                widths = [int(r.size) * np.dtype(r.dtype).itemsize
-                          for r in rows]
-                e = {"pos": pos, "si": place_index[src],
-                     "di": place_index[dest], "rows": rows,
-                     "widths": widths, "m": len(rows),
-                     "wmax": max(widths, default=0),
-                     "nbytes": int(sum(widths)), "manifest": manifest,
-                     "dev": any(isinstance(r, jax.Array) for r in rows)}
-            entries[pos] = e
-            stats.payloads += 1
-            stats.rows += e["m"]
-            stats.row_bytes += e["nbytes"]
+                if fused:
+                    raw_fn = getattr(col, "encode_rows_raw", None)
+                    raw = raw_fn(payload) if raw_fn is not None else None
+                    if raw is not None:
+                        # typed chunk matrix: the encode+pack call turns
+                        # it into wire words on device — no host byte
+                        # view at all
+                        mat, manifest = raw
+                        m, k = int(mat.shape[0]), int(mat.shape[1])
+                        nb = k * np.dtype(mat.dtype).itemsize
+                        entries[pos] = {
+                            "pos": pos, "si": place_index[src],
+                            "di": place_index[dest], "raw": mat, "m": m,
+                            "wmax": nb, "nbytes": m * nb,
+                            "manifest": manifest,
+                            "dev": isinstance(mat, jax.Array)}
+                        stats.payloads += 1
+                        stats.rows += m
+                        stats.row_bytes += m * nb
+                        continue
+                rows, manifest = _encode_rows(col, payload)
+                if isinstance(rows, np.ndarray) and rows.ndim == 2:
+                    # chunk payloads stay one (m, w) matrix end to end: the
+                    # pack is a single block copy, never m row assignments
+                    e = {"pos": pos, "si": place_index[src],
+                         "di": place_index[dest], "mat": rows,
+                         "m": int(rows.shape[0]), "wmax": int(rows.shape[1]),
+                         "nbytes": int(rows.size), "manifest": manifest,
+                         "dev": False}
+                else:
+                    rows = list(rows)
+                    widths = [int(r.size) * np.dtype(r.dtype).itemsize
+                              for r in rows]
+                    e = {"pos": pos, "si": place_index[src],
+                         "di": place_index[dest], "rows": rows,
+                         "widths": widths, "m": len(rows),
+                         "wmax": max(widths, default=0),
+                         "nbytes": int(sum(widths)), "manifest": manifest,
+                         "dev": any(isinstance(r, jax.Array) for r in rows)}
+                entries[pos] = e
+                stats.payloads += 1
+                stats.rows += e["m"]
+                stats.row_bytes += e["nbytes"]
 
-        delivered = list(payloads)
-        # decode zero-row payloads host-side (delivered objects are
-        # reconstructions even when nothing crossed the wire); bucket
-        # the rest by padded row-width class — one masked all_to_all per
-        # class, so small metadata rows (a pickled Sequence) never pad
-        # to a KV page's width when both ride one window
-        buckets: dict[int, list[dict]] = {}
-        for e in entries.values():
-            if e["m"] == 0:
-                col, src, dest, _ = payloads[e["pos"]]
-                delivered[e["pos"]] = (col, src, dest,
-                                       col.decode_rows([], e["manifest"]))
-                continue
-            buckets.setdefault(self._width_class(e["wmax"]), []).append(e)
+            delivered = list(payloads)
+            # decode zero-row payloads host-side (delivered objects are
+            # reconstructions even when nothing crossed the wire); bucket
+            # the rest by padded row-width class — one masked all_to_all per
+            # class, so small metadata rows (a pickled Sequence) never pad
+            # to a KV page's width when both ride one window
+            buckets: dict[int, list[dict]] = {}
+            for e in entries.values():
+                if e["m"] == 0:
+                    col, src, dest, _ = payloads[e["pos"]]
+                    delivered[e["pos"]] = (col, src, dest,
+                                           col.decode_rows([], e["manifest"]))
+                    continue
+                buckets.setdefault(self._width_class(e["wmax"]), []).append(e)
         for W, bucket in sorted(buckets.items()):
             if fused:
                 self._exchange_bucket_fused(n, W, bucket, payloads,
@@ -441,28 +451,29 @@ class DeviceTransport:
     def _exchange_bucket(self, n, W, bucket, payloads, delivered, stats):
         """One masked ``all_to_all`` over the entries of one row-width
         class; decodes straight into ``delivered``."""
-        per_src: list[list[dict]] = [[] for _ in range(n)]
-        # each sender's prefix is grouped by destination (stable within
-        # a destination: registration order) — the receive side then
-        # reads contiguous blocks per (src, dest) pair
-        for e in bucket:
-            per_src[e["si"]].append(e)
-        for si in range(n):
-            per_src[si].sort(key=lambda e: e["di"])
-        ship = np.zeros((n, n), np.int32)
-        for e in bucket:
-            ship[e["si"], e["di"]] += e["m"]
-        # capacity covers BOTH sides of the exchange — the busiest
-        # sender's outgoing total and the busiest receiver's incoming
-        # total (_ship_hop's receive prefix lands in the same S slots;
-        # fan-in past S would silently drop rows) — rounded to the next
-        # power of two so successive windows of similar traffic reuse
-        # one (n, S, W) jit specialization instead of recompiling per
-        # exact row count
-        S = int(max(ship.sum(axis=1).max(), ship.sum(axis=0).max(), 1))
-        S = 1 << (S - 1).bit_length()
-        buf = self._pack(per_src, n, S, W,
-                         device=any(e["dev"] for e in bucket))
+        with telemetry.span("transport.stage"):
+            per_src: list[list[dict]] = [[] for _ in range(n)]
+            # each sender's prefix is grouped by destination (stable within
+            # a destination: registration order) — the receive side then
+            # reads contiguous blocks per (src, dest) pair
+            for e in bucket:
+                per_src[e["si"]].append(e)
+            for si in range(n):
+                per_src[si].sort(key=lambda e: e["di"])
+            ship = np.zeros((n, n), np.int32)
+            for e in bucket:
+                ship[e["si"], e["di"]] += e["m"]
+            # capacity covers BOTH sides of the exchange — the busiest
+            # sender's outgoing total and the busiest receiver's incoming
+            # total (_ship_hop's receive prefix lands in the same S slots;
+            # fan-in past S would silently drop rows) — rounded to the next
+            # power of two so successive windows of similar traffic reuse
+            # one (n, S, W) jit specialization instead of recompiling per
+            # exact row count
+            S = int(max(ship.sum(axis=1).max(), ship.sum(axis=0).max(), 1))
+            S = 1 << (S - 1).bit_length()
+            buf = self._pack(per_src, n, S, W,
+                             device=any(e["dev"] for e in bucket))
 
         recv = self._exchange_fn(n, S, W)(buf, ship)
         stats.exchanges += 1
@@ -470,26 +481,28 @@ class DeviceTransport:
         wire = int(ship.sum()) * W
         stats.wire_bytes += wire
         stats.pad_waste_bytes += wire - sum(e["nbytes"] for e in bucket)
+        stats.buffer_bytes += n * S * W
 
         # receive layout: shard d's prefix holds, for src 0..n-1, the
         # ship[src, d] rows that src packed for d, in src's order.
         # Host-decoded entries copy only their own row block to host —
         # never the whole (n, S, W) padded capacity, which would drag
         # the device-resident KV rows of a mixed bucket along with it
-        offsets = np.zeros(n, np.int64)
-        for si in range(n):
-            for e in per_src[si]:
-                di, m = e["di"], e["m"]
-                lo = int(offsets[di])
-                block = recv[di, lo:lo + m]
-                if not e["dev"]:
-                    block = np.asarray(block)
-                offsets[di] += m
-                rows = block if "mat" in e \
-                    else [block[i] for i in range(m)]
-                col, src, dest, _ = payloads[e["pos"]]
-                delivered[e["pos"]] = (
-                    col, src, dest, col.decode_rows(rows, e["manifest"]))
+        with telemetry.span("transport.unstage"):
+            offsets = np.zeros(n, np.int64)
+            for si in range(n):
+                for e in per_src[si]:
+                    di, m = e["di"], e["m"]
+                    lo = int(offsets[di])
+                    block = recv[di, lo:lo + m]
+                    if not e["dev"]:
+                        block = np.asarray(block)
+                    offsets[di] += m
+                    rows = block if "mat" in e \
+                        else [block[i] for i in range(m)]
+                    col, src, dest, _ = payloads[e["pos"]]
+                    delivered[e["pos"]] = (
+                        col, src, dest, col.decode_rows(rows, e["manifest"]))
 
     def _pack(self, per_src, n, S, W, *, device):
         """(n, S, W) uint8 send buffer under the prefix invariant; built
@@ -560,77 +573,80 @@ class DeviceTransport:
         from ..kernels.reloc_codec import to_words
         from .collections import _host_bytes, _row_words
 
-        ship = np.zeros((n, n), np.int32)
-        for e in bucket:
-            ship[e["si"], e["di"]] += e["m"]
-        Sp = 1 << (int(ship.max()) - 1).bit_length()
-        pairs = n * n
-        cap = max(_EXCHANGE_BYTES // (pairs * W), 1)
-        Sp = min(Sp, 1 << (cap.bit_length() - 1))
-        rounds = -(-int(ship.max()) // Sp)
-        Sv = rounds * Sp                  # slots per pair over all rounds
-
-        # slot assignment: each entry's rows land at [p0, p0+m) inside
-        # its pair's block, accumulated in registration order
-        fill = np.zeros((n, n), np.int64)
-        for e in bucket:
-            e["p0"] = int(fill[e["si"], e["di"]])
-            fill[e["si"], e["di"]] += e["m"]
-
-        wid_tab = np.zeros((pairs, Sv), np.int32)
-        src_tab = np.zeros((pairs, Sv), np.int32)   # row index / offset
-        raw_keys = {(str(np.dtype(e["raw"].dtype)), int(e["raw"].shape[1]))
-                    for e in bucket if "raw" in e}
-        if len(raw_keys) == 1 and all("raw" in e for e in bucket):
-            # homogeneous typed bucket (the chunk-steal hot path): the
-            # encode+pack kernel reads straight off the concatenated
-            # chunk matrices
-            mats, base = [], 0
+        with telemetry.span("transport.stage"):
+            ship = np.zeros((n, n), np.int32)
             for e in bucket:
-                pr, s0 = e["si"] * n + e["di"], e["p0"]
-                src_tab[pr, s0:s0 + e["m"]] = np.arange(base, base + e["m"])
-                wid_tab[pr, s0:s0 + e["m"]] = e["wmax"]
-                mats.append(e["raw"])
-                base += e["m"]
-            if any(isinstance(x, jax.Array) for x in mats):
-                src = jnp.concatenate([jnp.asarray(x) for x in mats])
-            else:
-                src = np.concatenate(mats)
-            pack = ops.reloc_encode_pack
-        else:
-            # mixed bucket: every entry contributes word rows to one
-            # arena; a single pack kernel gathers them into slots
-            pieces, dev, base = [], False, 0
+                ship[e["si"], e["di"]] += e["m"]
+            Sp = 1 << (int(ship.max()) - 1).bit_length()
+            pairs = n * n
+            cap = max(_EXCHANGE_BYTES // (pairs * W), 1)
+            Sp = min(Sp, 1 << (cap.bit_length() - 1))
+            rounds = -(-int(ship.max()) // Sp)
+            Sv = rounds * Sp                  # slots per pair over all rounds
+
+            # slot assignment: each entry's rows land at [p0, p0+m) inside
+            # its pair's block, accumulated in registration order
+            fill = np.zeros((n, n), np.int64)
             for e in bucket:
-                pr, s0 = e["si"] * n + e["di"], e["p0"]
-                if "rows" in e:
-                    for j, (r, w) in enumerate(zip(e["rows"],
-                                                   e["widths"])):
-                        r = _row_words(r)
-                        src_tab[pr, s0 + j] = base
-                        wid_tab[pr, s0 + j] = w
-                        dev = dev or isinstance(r, jax.Array)
-                        pieces.append(r)
-                        base += int(r.shape[0])
-                    continue
-                if "mat" in e:
-                    wm = e["mat"]
-                    wm = np.pad(wm, ((0, 0), (0, (-wm.shape[1]) % 4)))
-                    wm = np.ascontiguousarray(wm).view(np.uint32)
+                e["p0"] = int(fill[e["si"], e["di"]])
+                fill[e["si"], e["di"]] += e["m"]
+
+            wid_tab = np.zeros((pairs, Sv), np.int32)
+            src_tab = np.zeros((pairs, Sv), np.int32)   # row index / offset
+            raw_keys = {(str(np.dtype(e["raw"].dtype)),
+                         int(e["raw"].shape[1]))
+                        for e in bucket if "raw" in e}
+            if len(raw_keys) == 1 and all("raw" in e for e in bucket):
+                # homogeneous typed bucket (the chunk-steal hot path): the
+                # encode+pack kernel reads straight off the concatenated
+                # chunk matrices
+                mats, base = [], 0
+                for e in bucket:
+                    pr, s0 = e["si"] * n + e["di"], e["p0"]
+                    src_tab[pr, s0:s0 + e["m"]] = np.arange(
+                        base, base + e["m"])
+                    wid_tab[pr, s0:s0 + e["m"]] = e["wmax"]
+                    mats.append(e["raw"])
+                    base += e["m"]
+                if any(isinstance(x, jax.Array) for x in mats):
+                    src = jnp.concatenate([jnp.asarray(x) for x in mats])
                 else:
-                    wm = to_words(e["raw"])
-                m, wq = e["m"], int(wm.shape[1])
-                src_tab[pr, s0:s0 + m] = base + wq * np.arange(m)
-                wid_tab[pr, s0:s0 + m] = 4 * wq
-                dev = dev or isinstance(wm, jax.Array)
-                pieces.append(wm.reshape(-1))
-                base += m * wq
-            if dev:
-                src = jnp.concatenate([jnp.asarray(p) for p in pieces])
+                    src = np.concatenate(mats)
+                pack = ops.reloc_encode_pack
             else:
-                src = np.concatenate(pieces)
-            del pieces
-            pack = ops.reloc_pack_rows
+                # mixed bucket: every entry contributes word rows to one
+                # arena; a single pack kernel gathers them into slots
+                pieces, dev, base = [], False, 0
+                for e in bucket:
+                    pr, s0 = e["si"] * n + e["di"], e["p0"]
+                    if "rows" in e:
+                        for j, (r, w) in enumerate(zip(e["rows"],
+                                                       e["widths"])):
+                            r = _row_words(r)
+                            src_tab[pr, s0 + j] = base
+                            wid_tab[pr, s0 + j] = w
+                            dev = dev or isinstance(r, jax.Array)
+                            pieces.append(r)
+                            base += int(r.shape[0])
+                        continue
+                    if "mat" in e:
+                        wm = e["mat"]
+                        wm = np.pad(wm, ((0, 0), (0, (-wm.shape[1]) % 4)))
+                        wm = np.ascontiguousarray(wm).view(np.uint32)
+                    else:
+                        wm = to_words(e["raw"])
+                    m, wq = e["m"], int(wm.shape[1])
+                    src_tab[pr, s0:s0 + m] = base + wq * np.arange(m)
+                    wid_tab[pr, s0:s0 + m] = 4 * wq
+                    dev = dev or isinstance(wm, jax.Array)
+                    pieces.append(wm.reshape(-1))
+                    base += m * wq
+                if dev:
+                    src = jnp.concatenate([jnp.asarray(p) for p in pieces])
+                else:
+                    src = np.concatenate(pieces)
+                del pieces
+                pack = ops.reloc_pack_rows
 
         # recv[di, si] is exactly what si packed for di; each entry's
         # rows are the contiguous slot range it claimed above.  Typed
@@ -645,29 +661,33 @@ class DeviceTransport:
             recv = self._fused_exchange_fn(n, Sp, W)(
                 buf.reshape((n, n) + buf.shape[1:]))
             del buf
-            for e in bucket:
-                a = max(e["p0"], lo)
-                b = min(e["p0"] + e["m"], lo + Sp)
-                if a < b:
-                    blocks[id(e)].append(_slot_rows(
-                        recv[e["di"], e["si"]], a - lo, b - a, W))
+            with telemetry.span("transport.unstage"):
+                for e in bucket:
+                    a = max(e["p0"], lo)
+                    b = min(e["p0"] + e["m"], lo + Sp)
+                    if a < b:
+                        blocks[id(e)].append(_slot_rows(
+                            recv[e["di"], e["si"]], a - lo, b - a, W))
             del recv
         stats.exchanges += rounds
         stats.width = max(stats.width, W)
         wire = int(ship.sum()) * W
         stats.wire_bytes += wire
         stats.pad_waste_bytes += wire - sum(e["nbytes"] for e in bucket)
+        stats.buffer_bytes += pairs * Sp * W * rounds
 
-        for e in bucket:
-            parts = blocks[id(e)]
-            block = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-            if not (e["dev"] or "raw" in e):
-                block = _host_bytes(block)
-            rows = block if ("mat" in e or "raw" in e) \
-                else [block[i] for i in range(e["m"])]
-            col, src_, dest, _ = payloads[e["pos"]]
-            delivered[e["pos"]] = (
-                col, src_, dest, col.decode_rows(rows, e["manifest"]))
+        with telemetry.span("transport.unstage"):
+            for e in bucket:
+                parts = blocks[id(e)]
+                block = parts[0] if len(parts) == 1 \
+                    else jnp.concatenate(parts)
+                if not (e["dev"] or "raw" in e):
+                    block = _host_bytes(block)
+                rows = block if ("mat" in e or "raw" in e) \
+                    else [block[i] for i in range(e["m"])]
+                col, src_, dest, _ = payloads[e["pos"]]
+                delivered[e["pos"]] = (
+                    col, src_, dest, col.decode_rows(rows, e["manifest"]))
 
 
 def _slot_rows(stream, r0: int, m: int, width: int):

@@ -41,7 +41,10 @@ All kernels run under ``interpret=True`` on CPU (the parity target,
 bit-identical to :mod:`repro.kernels.ref`); the compiled path is the
 TPU execution target.
 
-Jitted kernel instances are cached per static shape in a bounded
+The jitted entry points are named for what they run, so a profile
+names its programs ``jit_reloc_encode_pack``, ``jit_reloc_pack`` and
+``jit_reloc_decode`` (the kernels inside keep their ``name``).  Jitted
+kernel instances are cached per static shape in a bounded
 :class:`LRUCache` so long elastic runs (where the place count changes
 on every resize) cannot grow the cache without bound.
 """
@@ -324,12 +327,12 @@ def _gather_call(pairs: int, slots: int, width: int, rows: int,
         name="reloc_pack_rows",
     )
 
-    def run(arena, offsets, n_live):
+    def reloc_gather(arena, offsets, n_live):
         zeros = jnp.zeros((pairs, chunks, 1, _LANES), jnp.uint32)
         return call(offsets.reshape(pairs, 1, slots),
                     n_live.reshape(pairs, 1, slots), arena, zeros)
 
-    fn = jax.jit(run)
+    fn = jax.jit(reloc_gather)
     _CACHE.put(key, fn)
     return fn
 
@@ -370,8 +373,12 @@ def _pack_rows_call(pairs: int, slots: int, width: int, n: int,
         wq = width // 4
         gather = _gather_call(pairs, slots, width, _arena_rows(n, wq),
                               interpret)
-        fn = jax.jit(lambda words, offsets, widths: gather(
-            _arena(words, wq), offsets, _live_words(widths, wq)))
+
+        def reloc_pack(words, offsets, widths):
+            return gather(_arena(words, wq), offsets,
+                          _live_words(widths, wq))
+
+        fn = jax.jit(reloc_pack)
         _CACHE.put(key, fn)
     return fn
 
@@ -390,13 +397,13 @@ def _encode_pack_call(pairs: int, slots: int, width: int, m: int, k: int,
     gather = _gather_call(pairs, slots, width, _arena_rows(m * wq, wq),
                           interpret)
 
-    def run(mat, idx, widths):
+    def reloc_encode_pack(mat, idx, widths):
         words = jnp.pad(to_words(mat), ((0, 0), (0, wq - kw)))
         offsets = jnp.clip(idx, 0, m - 1) * wq
         return gather(_arena(words.reshape(-1), wq), offsets,
                       _live_words(widths, wq))
 
-    fn = jax.jit(run)
+    fn = jax.jit(reloc_encode_pack)
     _CACHE.put(key, fn)
     return fn
 
@@ -462,11 +469,11 @@ def _decode_call(m: int, wq: int, nbytes: int, dtype, interpret: bool):
         name="reloc_decode_rows",
     )
 
-    def run(words):
+    def reloc_decode(words):
         out = call(words)
         return out if dt.itemsize == 4 else from_words(out, dt, k)
 
-    fn = jax.jit(run)
+    fn = jax.jit(reloc_decode)
     _CACHE.put(key, fn)
     return fn
 

@@ -158,19 +158,21 @@ class DecodeEngine:
         if n == 0:
             return 0.0
         prepared = []   # (chunk, stacked state, tokens) — built untimed
-        for lo in range(0, n, self.max_batch):
-            chunk = seq_kvs[lo:lo + self.max_batch]
-            bucket = self._bucket(len(chunk))
-            pad = bucket - len(chunk)
-            state = _stack_states([kv.state for kv in chunk]
-                                  + [self._pad_state] * pad)
-            tokens = jnp.concatenate(
-                [jnp.asarray(kv.token) for kv in chunk]
-                + [self._pad_token] * pad, axis=0)
-            if bucket not in self._warm:   # compile untimed
-                jax.block_until_ready(self._step(self.params, state, tokens))
-                self._warm.add(bucket)
-            prepared.append((chunk, state, tokens))
+        with telemetry.span("serve.stack", seqs=n):
+            for lo in range(0, n, self.max_batch):
+                chunk = seq_kvs[lo:lo + self.max_batch]
+                bucket = self._bucket(len(chunk))
+                pad = bucket - len(chunk)
+                state = _stack_states([kv.state for kv in chunk]
+                                      + [self._pad_state] * pad)
+                tokens = jnp.concatenate(
+                    [jnp.asarray(kv.token) for kv in chunk]
+                    + [self._pad_token] * pad, axis=0)
+                if bucket not in self._warm:   # compile untimed
+                    jax.block_until_ready(
+                        self._step(self.params, state, tokens))
+                    self._warm.add(bucket)
+                prepared.append((chunk, state, tokens))
         # drain the async dispatch queue (stacking above, unstacking from
         # earlier calls) so the timed window measures *this* decode only
         jax.block_until_ready([s for _, s, _ in prepared])
@@ -185,11 +187,13 @@ class DecodeEngine:
             dt = time.perf_counter() - t0
         if telemetry.enabled():
             telemetry.observe("serve.decode_s", dt)
-        for (chunk, _, _), (out_state, out_tokens) in zip(prepared, outs):
-            for i, (kv, new_state) in enumerate(
-                    zip(chunk, _unstack_state(out_state, len(chunk)))):
-                kv.state = new_state
-                kv.token = out_tokens[i:i + 1]
+        with telemetry.span("serve.unstack", seqs=n):
+            for (chunk, _, _), (out_state, out_tokens) in zip(prepared,
+                                                              outs):
+                for i, (kv, new_state) in enumerate(
+                        zip(chunk, _unstack_state(out_state, len(chunk)))):
+                    kv.state = new_state
+                    kv.token = out_tokens[i:i + 1]
         self.steps += 1
         self.tokens_decoded += n
         return dt

@@ -130,7 +130,15 @@ class ElasticServingDriver:
         just shed its sequences is exactly the one raw counts would
         refill.  ``place`` pins the placement (a sticky-session router,
         or a skewed-arrival harness); ``admission="count"`` at
-        construction restores the raw-count policy."""
+        construction restores the raw-count policy.  Traced as a
+        ``serve.admit`` span carrying the admitted ``seq`` id."""
+        with telemetry.span("serve.admit") as sp:
+            sid = self._admit(prompt_len, max_new, place)
+            if sp:
+                sp.set(seq=sid)
+            return sid
+
+    def _admit(self, prompt_len, max_new, place) -> int | None:
         members = list(self.group.members)
         counts = np.asarray([self.seqs.local_size(p) for p in members])
         if place is not None:

@@ -192,16 +192,18 @@ class TestMetrics:
     def test_transport_stats_merge_and_as_dict(self):
         a = TransportStats(kind="device", payloads=2, local=1, rows=10,
                            row_bytes=80, wire_bytes=128,
-                           pad_waste_bytes=48, width=16, exchanges=1,
-                           codec_backend="xla")
+                           pad_waste_bytes=48, buffer_bytes=512, width=16,
+                           exchanges=1, codec_backend="xla")
         b = TransportStats(kind="device", payloads=3, rows=5, row_bytes=40,
-                           wire_bytes=64, pad_waste_bytes=24, width=8,
-                           exchanges=2, codec_backend="pallas_interpret")
+                           wire_bytes=64, pad_waste_bytes=24,
+                           buffer_bytes=256, width=8, exchanges=2,
+                           codec_backend="pallas_interpret")
         out = a.merge(b)
         assert out is a                     # merge returns self
         assert (a.payloads, a.local, a.rows) == (5, 1, 15)
         assert (a.row_bytes, a.wire_bytes, a.exchanges) == (120, 192, 3)
         assert a.pad_waste_bytes == 72
+        assert a.buffer_bytes == 768
         assert a.width == 16                # high-water mark, not a sum
         assert a.codec_backend == "pallas_interpret"   # latest window
         # an empty backend never clobbers a recorded one
@@ -210,7 +212,8 @@ class TestMetrics:
         d = a.as_dict("t.")
         assert d == {"t.payloads": 5, "t.local": 1, "t.rows": 15,
                      "t.row_bytes": 120, "t.wire_bytes": 192,
-                     "t.pad_waste_bytes": 72, "t.width": 16,
+                     "t.pad_waste_bytes": 72, "t.buffer_bytes": 768,
+                     "t.width": 16,
                      "t.exchanges": 3,
                      "t.codec_backend": "pallas_interpret"}
 
@@ -296,11 +299,15 @@ class TestRelocationInstrumentation:
         ex = by_name["transport.exchange"][0]["args"]
         assert ex["kind"] == "host"
         assert ex["seq"] == 0
-        # metrics landed alongside the spans
+        # the exchange's own numbers ride its span
+        assert ex["payloads"] >= 1
+        assert ex["wire_bytes"] == ex["row_bytes"] == ex["buffer_bytes"] == 0
+        # metrics landed alongside the spans; the exchange keeps no
+        # per-exchange histogram, only the lifetime counters
         m = telemetry.metrics_dict()
         assert m["reloc.window_s.count"] == 1
         assert m["reloc.window_bytes.count"] == 1
-        assert m["transport.exchange_wire_bytes.count"] == 1
+        assert not any(k.startswith("transport.exchange_") for k in m)
         assert m["transport.host.payloads"] >= 1
 
     def test_uninstrumented_run_records_nothing(self):
