@@ -71,19 +71,42 @@ def _stack_states(states: list) -> dict:
 
 
 def _unstack_state(state: dict, n: int) -> list:
-    """Inverse of :func:`_stack_states`: the first ``n`` batch slices."""
+    """Inverse of :func:`_stack_states`: the first ``n`` batch slices.
+    A leaf may hold several rows per sequence on its batch axis (the
+    mLSTM state merges batch and heads, batch-major), so each sequence
+    takes its own share of the rows."""
+    batch = state["pos"].shape[0]
+
+    def rows(i, axis):
+        def take(a):
+            k = a.shape[axis] // batch
+            return jax.lax.slice_in_dim(a, i * k, (i + 1) * k, axis=axis)
+        return take
+
     out = []
     for i in range(n):
         out.append({
             "pos": state["pos"][i:i + 1],
-            "prefix": jax.tree_util.tree_map(
-                lambda a: a[i:i + 1], state["prefix"]),
-            "suffix": jax.tree_util.tree_map(
-                lambda a: a[i:i + 1], state["suffix"]),
-            "scan": jax.tree_util.tree_map(
-                lambda a: a[:, i:i + 1], state["scan"]),
+            "prefix": jax.tree_util.tree_map(rows(i, 0), state["prefix"]),
+            "suffix": jax.tree_util.tree_map(rows(i, 0), state["suffix"]),
+            "scan": jax.tree_util.tree_map(rows(i, 1), state["scan"]),
         })
     return out
+
+
+def serve_stack(states: list, tokens: list):
+    """The stack program: a micro-batch's batch-1 states and ``(1, 1)``
+    tokens, padded to its bucket, as one batch state and ``(B, 1)``
+    tokens."""
+    return _stack_states(states), jnp.concatenate(tokens, axis=0)
+
+
+def serve_unstack(state: dict, tokens):
+    """The unstack program: every batch slice of a step's output state
+    and tokens, padding slots included, so one program serves a bucket
+    whatever the micro-batch's length (static slices)."""
+    n = tokens.shape[0]
+    return _unstack_state(state, n), [tokens[i:i + 1] for i in range(n)]
 
 
 class DecodeEngine:
@@ -98,7 +121,9 @@ class DecodeEngine:
     Micro-batch shapes are padded to power-of-two buckets so the jit
     cache stays small (≤ log2(max_batch)+1 entries); each bucket is
     warmed untimed on first use so compilation never pollutes a measured
-    decode time.
+    decode time.  Stacking the slices into a batch and writing the
+    step's output back are one compiled program each per bucket, so a
+    micro-batch costs three dispatches, not one per leaf and sequence.
     """
 
     def __init__(self, cfg=None, *, s_cache: int = 128, max_batch: int = 8,
@@ -118,6 +143,8 @@ class DecodeEngine:
             return state, jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
 
         self._step = jax.jit(serve_step)
+        self._stack = jax.jit(serve_stack)
+        self._unstack = jax.jit(serve_unstack)
         # host-side batch-1 template: admission builds SeqKVs from this
         # and the driver bridges them to device via ``kv.to_device``
         self._template = jax.tree_util.tree_map(
@@ -163,15 +190,14 @@ class DecodeEngine:
                 chunk = seq_kvs[lo:lo + self.max_batch]
                 bucket = self._bucket(len(chunk))
                 pad = bucket - len(chunk)
-                state = _stack_states([kv.state for kv in chunk]
-                                      + [self._pad_state] * pad)
-                tokens = jnp.concatenate(
-                    [jnp.asarray(kv.token) for kv in chunk]
-                    + [self._pad_token] * pad, axis=0)
+                state, tokens = self._stack(
+                    [kv.state for kv in chunk] + [self._pad_state] * pad,
+                    [kv.token for kv in chunk] + [self._pad_token] * pad)
                 if bucket not in self._warm:   # compile untimed
-                    jax.block_until_ready(
-                        self._step(self.params, state, tokens))
+                    jax.block_until_ready(self._unstack(
+                        *self._step(self.params, state, tokens)))
                     self._warm.add(bucket)
+                    telemetry.inc("serve.batch_programs_built")
                 prepared.append((chunk, state, tokens))
         # drain the async dispatch queue (stacking above, unstacking from
         # earlier calls) so the timed window measures *this* decode only
@@ -185,15 +211,18 @@ class DecodeEngine:
                 outs.append(out)
             jax.block_until_ready(outs)
             dt = time.perf_counter() - t0
+        chunks = [chunk for chunk, _, _ in prepared]
+        # drop the stacked inputs before their slices are made, so the
+        # peak holds one batch of KV less
+        del prepared, state, tokens
         if telemetry.enabled():
             telemetry.observe("serve.decode_s", dt)
         with telemetry.span("serve.unstack", seqs=n):
-            for (chunk, _, _), (out_state, out_tokens) in zip(prepared,
-                                                              outs):
-                for i, (kv, new_state) in enumerate(
-                        zip(chunk, _unstack_state(out_state, len(chunk)))):
+            for chunk, out in zip(chunks, outs):
+                states, tokens = self._unstack(*out)
+                for kv, new_state, token in zip(chunk, states, tokens):
                     kv.state = new_state
-                    kv.token = out_tokens[i:i + 1]
+                    kv.token = token
         self.steps += 1
         self.tokens_decoded += n
         return dt
