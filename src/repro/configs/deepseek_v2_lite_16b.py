@@ -1,11 +1,9 @@
-"""deepseek-v2-lite-16b [moe] — MLA kv_lora=512, routed experts top-6
-[arXiv:2405.04434; hf].
-
-Pool header says "MoE 64e top-6 d_ff=1408" while its note says
-"2 shared+160 routed"; we follow the header (64 routed, top-6, 2 shared)
-— discrepancy recorded in DESIGN.md §Arch-applicability.
+"""deepseek-v2-lite-16b [moe] — MLA (kv_lora 512, no q-LoRA, YaRN rope),
+one dense layer, then 26 layers of 64 routed experts (top-6, softmax,
+unnormalised weights) beside 2 shared experts [arXiv:2405.04434;
+https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/blob/main/config.json].
 """
-from ..models.config import LayerSlot, ModelConfig
+from ..models.config import LayerSlot, ModelConfig, Yarn
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -22,6 +20,7 @@ CONFIG = ModelConfig(
     n_shared_experts=2,
     top_k=6,
     d_ff_expert=1408,
+    norm_topk_prob=False,
     mla=True,
     kv_lora_rank=512,
     q_lora_rank=0,              # v2-lite: full-rank q
@@ -29,6 +28,8 @@ CONFIG = ModelConfig(
     qk_rope_dim=64,
     v_head_dim=128,
     rope_theta=10000.0,
+    yarn=Yarn(factor=40.0, original_max_position=4096, beta_fast=32.0,
+              beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707),
     tie_embeddings=False,
     loss_chunk=512,
 )

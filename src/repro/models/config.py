@@ -4,7 +4,7 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Optional
 
-__all__ = ["ModelConfig", "LayerSlot"]
+__all__ = ["ModelConfig", "LayerSlot", "Yarn"]
 
 
 @dataclass(frozen=True)
@@ -17,6 +17,18 @@ class LayerSlot:
     """
     mixer: str = "attn_global"
     ffn: str = "dense"
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling, as a ``rope_scaling`` of type ``yarn`` in a
+    DeepSeek-V2 config.json (``DeepseekV2YarnRotaryEmbedding``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
 
 
 @dataclass(frozen=True)
@@ -37,6 +49,7 @@ class ModelConfig:
     # attention details
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    yarn: Optional[Yarn] = None      # YaRN-scaled rope (DeepSeek-V2)
     mrope_sections: tuple[int, ...] = ()   # qwen2-vl M-RoPE
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
@@ -49,6 +62,10 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0
+    norm_topk_prob: bool = True      # renormalise the top-k router weights
+    # the routed experts this chip holds (expert parallelism: the layer
+    # computes their part of the result); None holds all n_experts
+    held_experts: Optional[range] = None
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
@@ -94,6 +111,13 @@ class ModelConfig:
     remat: str = "none"              # none | full | dots
     scan_layers: bool = True
 
+    def __post_init__(self):
+        r = self.held_experts
+        if r is not None and not (len(r) and r.step == 1 and r.start >= 0
+                                  and r.stop <= self.n_experts):
+            raise ValueError(f"held_experts {r} is not a range of the "
+                             f"{self.n_experts} routed experts")
+
     # ------------------------------------------------------------------
     @property
     def vocab_padded(self) -> int:
@@ -108,6 +132,13 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def expert_range(self) -> range:
+        """The routed experts held here: ``held_experts`` or all."""
+        if self.held_experts is None:
+            return range(self.n_experts)
+        return self.held_experts
 
     @property
     def uses_attention(self) -> bool:
@@ -145,7 +176,8 @@ class ModelConfig:
         )
         if self.n_experts:
             defaults.update(n_experts=4, top_k=2, d_ff_expert=32,
-                            n_shared_experts=min(self.n_shared_experts, 1))
+                            n_shared_experts=min(self.n_shared_experts, 1),
+                            held_experts=None)
         if self.mla:
             defaults.update(kv_lora_rank=32, q_lora_rank=0, qk_nope_dim=16,
                             qk_rope_dim=8, v_head_dim=16)
@@ -199,7 +231,7 @@ class ModelConfig:
                 total += per_layer_dense_ffn
                 active += per_layer_dense_ffn
             elif slot.ffn == "moe":
-                total += self.n_experts * expert_ffn
+                total += len(self.expert_range) * expert_ffn
                 total += self.n_shared_experts * expert_ffn
                 total += d * self.n_experts  # router
                 active += (self.top_k + self.n_shared_experts) * expert_ffn

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 __all__ = [
     "dense_init", "dense", "rmsnorm_init", "rmsnorm", "embed_init",
-    "rope", "mrope", "swiglu_init", "swiglu", "geglu_init", "geglu",
+    "rope", "mrope", "yarn_inv_freq", "yarn_mscale", "swiglu_init",
+    "swiglu", "geglu_init", "geglu",
 ]
 
 
@@ -53,15 +54,49 @@ def embed_init(key, vocab: int, d: int, dtype):
     return {"table": _normal(key, (vocab, d), dtype, 0.02)}
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> jnp.ndarray:
+    """YaRN rope frequencies (``DeepseekV2YarnRotaryEmbedding``): each
+    lane blends the base frequency with it divided by ``factor``, by a
+    linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position``."""
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    extra = theta ** (-2.0 * i / dim)
+    mask = 1.0 - jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return extra / yarn.factor * (1.0 - mask) + extra * mask
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, yarn=None):
     """Rotary embedding. x: (B, S, H, D_head) — rotates over last dim.
-    positions: (B, S) int32."""
+    positions: (B, S) int32.  ``yarn`` (a ``config.Yarn``) scales the
+    frequencies and the rotated magnitudes as YaRN does."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        mag = 1.0
+    else:
+        freq = yarn_inv_freq(d, theta, yarn)
+        mag = (yarn_mscale(yarn.factor, yarn.mscale)
+               / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if mag != 1.0:
+        cos, sin = cos * mag, sin * mag
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin],

@@ -8,9 +8,12 @@ reuses ``core/relocation._pack_by_dest`` — the same packing code path
 the host CollectiveMoveManager models — executed as a dense
 ``lax.all_to_all`` over the expert-parallel mesh axis.
 
-Two execution modes:
+Execution modes:
 * ``expert_all_to_all`` — inside shard_map, explicit EP (paper-faithful
   flat all_to_all; hierarchical pod-local variant as a perf option).
+* ``held_experts_forward`` — the part of the result a chip's held
+  experts give: under shard_map with a psum (``expert_replicated``,
+  decode), or alone on one chip that holds a share of the experts.
 * dense fallback for single-device smoke tests (no mesh axis).
 """
 from __future__ import annotations
@@ -24,10 +27,12 @@ from ..compat import axis_size
 
 from ..core.relocation import _pack_by_dest
 from .config import ModelConfig
-from .layers import dense, dense_init, rmsnorm, rmsnorm_init, rope, swiglu, swiglu_init
+from .layers import (dense, dense_init, rmsnorm, rmsnorm_init, rope, swiglu,
+                     swiglu_init, yarn_mscale)
 
 __all__ = ["router_init", "route", "moe_init", "moe_forward_dense",
-           "expert_all_to_all", "expert_replicated", "mla_init",
+           "expert_all_to_all", "held_experts_forward", "routing_counts",
+           "expert_replicated", "mla_init", "mla_softmax_scale",
            "mla_forward", "mla_decode", "mla_decode_project",
            "mla_attend_cache"]
 
@@ -39,14 +44,16 @@ def router_init(key, d: int, n_experts: int, dtype):
     return {"w": dense_init(key, d, n_experts, jnp.float32)}
 
 
-def route(p, x, top_k: int, *, n_experts: int):
-    """Top-k softmax router (DeepSeek style: softmax over selected).
+def route(p, x, top_k: int, *, n_experts: int, normalize: bool = True):
+    """Top-k softmax router: softmax over all experts, greedy top-k; with
+    ``normalize`` the k weights are renormalised to sum to one.
 
     x: (T, d) → (weights (T, k) f32, idx (T, k) i32, aux_metrics)."""
     logits = x.astype(jnp.float32) @ p["w"]["w"].astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_i = jax.lax.top_k(probs, top_k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if normalize:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
     # aux load-balance loss (Switch/GShard form) + router z-loss
     me = jnp.mean(probs, axis=0)                                  # (E,)
     ce = jnp.mean(
@@ -63,7 +70,7 @@ def route(p, x, top_k: int, *, n_experts: int):
 def moe_init(key, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 4)
     d, dff = cfg.d_model, cfg.d_ff_expert
-    E = cfg.n_experts
+    E = len(cfg.expert_range)           # the bank holds the held experts
     scale_in = 1.0 / math.sqrt(d)
     scale_out = 1.0 / math.sqrt(dff)
 
@@ -75,7 +82,8 @@ def moe_init(key, cfg: ModelConfig, dtype):
             "wo": (jax.random.normal(k3, (E, dff, d), jnp.float32) * scale_out).astype(dtype),
         }
 
-    p = {"router": router_init(ks[0], d, E, dtype), "experts": ebank(ks[1])}
+    p = {"router": router_init(ks[0], d, cfg.n_experts, dtype),
+         "experts": ebank(ks[1])}
     if cfg.n_shared_experts:
         p["shared"] = swiglu_init(ks[2], d,
                                   dff * cfg.n_shared_experts, dtype)
@@ -95,7 +103,8 @@ def moe_forward_dense(p, cfg: ModelConfig, x):
     xt = x.reshape(-1, d)
     T = xt.shape[0]
     E, K = cfg.n_experts, cfg.top_k
-    w, idx, aux = route(p["router"], xt, K, n_experts=E)
+    w, idx, aux = route(p["router"], xt, K, n_experts=E,
+                        normalize=cfg.norm_topk_prob)
     # capacity floor min(T, 64) makes small batches (decode) drop-free:
     # an expert can receive at most T rows (top-k indices are distinct)
     cap = max(int(cfg.capacity_factor * T * K / E), min(T, 64))
@@ -133,7 +142,8 @@ def expert_all_to_all(router_p, local_bank, shared_p, cfg: ModelConfig, x, *,
     eps = E // n_shards                     # experts per shard
     cap = max(1, int(cfg.capacity_factor * T * K / E))
 
-    w, idx, aux = route(router_p, x, K, n_experts=E)
+    w, idx, aux = route(router_p, x, K, n_experts=E,
+                        normalize=cfg.norm_topk_prob)
     rows = jnp.repeat(x, K, axis=0)                      # (T*K, d)
     flat_dest = idx.reshape(-1)                          # global expert id
     # pack per global expert: (E, cap, d) == (n_shards, eps, cap, d)
@@ -166,33 +176,58 @@ def expert_all_to_all(router_p, local_bank, shared_p, cfg: ModelConfig, x, *,
     return out, aux
 
 
-def expert_replicated(router_p, local_bank, shared_p, cfg: ModelConfig, x, *,
-                      axis_name: str):
-    """Decode-mode EP: tokens replicated over the expert axis; each shard
-    filters the tokens routed to its local experts, computes, and the
-    combine is a psum over the expert axis (no all_to_all — the right
-    trade when T_local is tiny, e.g. one decode token per sequence)."""
+def held_experts_forward(router_p, bank, cfg: ModelConfig, x, first):
+    """The part of the routed experts' result that experts ``[first,
+    first + held)`` give, ``held`` being the bank's length: tokens x
+    (T, d) are routed over all ``cfg.n_experts``, assignments to other
+    experts go to a drop bin, and the held bank runs over the rest.
+
+    The capacity never drops an assignment to a held expert while
+    T <= 64 (an expert receives at most T rows: top-k indices are
+    distinct).  Returns (part (T, d) f32, aux, idx (T, k))."""
     T, d = x.shape
     E, K = cfg.n_experts, cfg.top_k
-    n_shards = axis_size(axis_name)
-    eps = E // n_shards
-    cap = max(int(2 * cfg.capacity_factor * T * K / n_shards), min(T, 64))
+    held = bank["wi"].shape[0]
+    cap = max(int(2 * cfg.capacity_factor * T * K * held / E), min(T, 64))
 
-    w, idx, aux = route(router_p, x, K, n_experts=E)
-    shard_id = jax.lax.axis_index(axis_name)
-    first = shard_id * eps
-    owned = (idx >= first) & (idx < first + eps)         # (T, K)
-    local_e = jnp.where(owned, idx - first, eps)         # eps = drop bin
+    w, idx, aux = route(router_p, x, K, n_experts=E,
+                        normalize=cfg.norm_topk_prob)
+    owned = (idx >= first) & (idx < first + held)        # (T, K)
+    local_e = jnp.where(owned, idx - first, held)        # held = drop bin
     rows = jnp.repeat(x, K, axis=0)
-    buf, valid, slot = _pack_by_dest(rows, local_e.reshape(-1), eps + 1, cap)
-    y = _expert_ffn(local_bank, buf[:eps].astype(x.dtype))  # (eps, cap, d)
+    buf, valid, slot = _pack_by_dest(rows, local_e.reshape(-1), held + 1, cap)
+    y = _expert_ffn(bank, buf[:held].astype(x.dtype))    # (held, cap, d)
     yf = jnp.concatenate([y, jnp.zeros((1,) + y.shape[1:], y.dtype)], 0) \
-            .reshape((eps + 1) * cap, d)
+            .reshape((held + 1) * cap, d)
     safe = jnp.where(slot >= 0, slot, 0)
     got = jnp.where((slot >= 0)[:, None], yf[safe], 0.0).reshape(T, K, d)
     wmask = jnp.where(owned, w, 0.0)
     out = jnp.einsum("tk,tkd->td", wmask.astype(jnp.float32),
                      got.astype(jnp.float32))
+    return out, aux, idx
+
+
+def routing_counts(idx, first: int, held: int, rows):
+    """int32 (3,): assignments of the ``rows`` (T,) bool tokens, those
+    to held experts ``[first, first + held)``, and held experts that
+    received at least one."""
+    real = jnp.broadcast_to(rows[:, None], idx.shape)
+    owned = real & (idx >= first) & (idx < first + held)
+    hit = jnp.any(jax.nn.one_hot(jnp.where(owned, idx - first, held), held,
+                                 dtype=jnp.bool_), axis=(0, 1))
+    return jnp.stack([jnp.sum(real), jnp.sum(owned),
+                      jnp.sum(hit)]).astype(jnp.int32)
+
+
+def expert_replicated(router_p, local_bank, shared_p, cfg: ModelConfig, x, *,
+                      axis_name: str):
+    """Decode-mode EP: tokens replicated over the expert axis; each shard
+    computes its local experts' part (:func:`held_experts_forward`), and
+    the combine is a psum over the expert axis (no all_to_all — the right
+    trade when T_local is tiny, e.g. one decode token per sequence)."""
+    eps = cfg.n_experts // axis_size(axis_name)
+    first = jax.lax.axis_index(axis_name) * eps
+    out, aux, _ = held_experts_forward(router_p, local_bank, cfg, x, first)
     out = jax.lax.psum(out, axis_name).astype(x.dtype)
     if shared_p is not None:
         out = out + swiglu(shared_p, x)
@@ -225,6 +260,15 @@ def mla_init(key, cfg: ModelConfig, dtype):
     return p
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(dn + dr)^-1/2``, times YaRN's ``mscale(factor, mscale_all_dim)``
+    squared under YaRN rope (``DeepseekV2Attention.softmax_scale``)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.yarn is not None:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
+
 def _mla_q(p, cfg: ModelConfig, x, positions):
     B, S, _ = x.shape
     H, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -236,7 +280,7 @@ def _mla_q(p, cfg: ModelConfig, x, positions):
     q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     pos = positions if positions.ndim == 2 else positions[0]
-    q_rope = rope(q_rope, pos, cfg.rope_theta)
+    q_rope = rope(q_rope, pos, cfg.rope_theta, cfg.yarn)
     return q_nope, q_rope
 
 
@@ -252,14 +296,14 @@ def mla_forward(p, cfg: ModelConfig, x, positions, *, impl=None):
     c_kv = rmsnorm(p["kv_norm"], dense(p["w_dkv"], x), cfg.norm_eps)  # (B,S,r)
     pos = positions if positions.ndim == 2 else positions[0]
     k_rope = rope(dense(p["w_krope"], x).reshape(B, S, 1, dr), pos,
-                  cfg.rope_theta)                                     # (B,S,1,dr)
+                  cfg.rope_theta, cfg.yarn)                           # (B,S,1,dr)
     k_nope = dense(p["w_uk"], c_kv).reshape(B, S, H, dn)
     v = dense(p["w_uv"], c_kv).reshape(B, S, H, dv)
 
     q = jnp.concatenate([q_nope, q_rope], axis=-1)                    # (B,S,H,dn+dr)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, dr))],
                         axis=-1)
-    sm_scale = 1.0 / math.sqrt(dn + dr)
+    sm_scale = mla_softmax_scale(cfg)
     # pad v to qk dim for the shared attention kernel, slice after
     if dv < dn + dr:
         v_p = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv)))
@@ -280,7 +324,7 @@ def mla_decode_project(p, cfg: ModelConfig, x, positions):
     c_new = rmsnorm(p["kv_norm"], dense(p["w_dkv"], x), cfg.norm_eps)
     pos = positions if positions.ndim == 2 else positions[0]
     kr_new = rope(dense(p["w_krope"], x).reshape(B, 1, 1, dr), pos,
-                  cfg.rope_theta)[:, 0, 0]
+                  cfg.rope_theta, cfg.yarn)[:, 0, 0]
     return (q_nope, q_rope), c_new[:, 0], kr_new
 
 
@@ -293,14 +337,14 @@ def mla_attend_cache(p, cfg: ModelConfig, q_pair, cache_ckv, cache_krope,
     B = q_nope.shape[0]
     H = cfg.n_heads
     r = cfg.kv_lora_rank
-    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    dn, dv = cfg.qk_nope_dim, cfg.v_head_dim
     # absorb W_uk into q: q_abs (B,1,H,r)
     w_uk = p["w_uk"]["w"].astype(jnp.float32).reshape(r, H, dn)
     q_abs = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), w_uk)
     valid = (cache_pos >= 0) & (cache_pos <= cur)
     ckv = cache_ckv.astype(jnp.float32)
     krp = cache_krope.astype(jnp.float32)
-    sm_scale = 1.0 / math.sqrt(dn + dr)
+    sm_scale = mla_softmax_scale(cfg)
     s = (jnp.einsum("bshr,btr->bhst", q_abs, ckv)[:, :, 0]
          + jnp.einsum("bshd,btd->bhst", q_rope.astype(jnp.float32),
                       krp)[:, :, 0]) * sm_scale

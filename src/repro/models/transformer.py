@@ -25,9 +25,9 @@ from .attention import (attn_attend_cache, attn_decode_project, attn_forward,
                         attn_init)
 from .config import LayerSlot, ModelConfig
 from .layers import dense, dense_init, embed_init, rmsnorm, rmsnorm_init, swiglu, swiglu_init
-from .moe import (expert_all_to_all, expert_replicated, mla_attend_cache,
-                  mla_decode_project, mla_forward, mla_init,
-                  moe_forward_dense, moe_init)
+from .moe import (expert_all_to_all, expert_replicated, held_experts_forward,
+                  mla_attend_cache, mla_decode_project, mla_forward, mla_init,
+                  moe_forward_dense, moe_init, routing_counts)
 from .parallel import Parallel, constrain
 from .rglru import rglru_block, rglru_block_init, rglru_block_step, rglru_empty_state
 from .ssm import (mlstm_block, mlstm_block_init, mlstm_block_step,
@@ -95,12 +95,29 @@ def _block_init(key, cfg: ModelConfig, slot: LayerSlot, dtype, *,
     return p
 
 
-def _moe_apply(p_moe, cfg: ModelConfig, par: Parallel, x, *, decode: bool):
-    """MoE island: collective relocation over the model axis."""
+def _moe_apply(p_moe, cfg: ModelConfig, par: Parallel, x, *, decode: bool,
+               rows=None):
+    """MoE island: collective relocation over the model axis.  Without a
+    model axis the chip computes its held experts' part of the result
+    (all of it when it holds every expert; training on every expert
+    takes the dense path), and with ``rows`` (T,) bool that path counts
+    those tokens' routing into ``aux["routed"]``
+    (:func:`moe.routing_counts`)."""
     B, S, d = x.shape
     if par.mesh is None or par.n_model_shards == 1 or cfg.n_experts < par.n_model_shards:
-        out, aux = moe_forward_dense(p_moe, cfg, x)
-        return out, aux
+        if not decode and cfg.held_experts is None:
+            return moe_forward_dense(p_moe, cfg, x)
+        held = cfg.expert_range
+        xt = x.reshape(-1, d)
+        out, aux, idx = held_experts_forward(p_moe["router"], p_moe["experts"],
+                                             cfg, xt, held.start)
+        if rows is not None:
+            aux = dict(aux, routed=routing_counts(idx, held.start, len(held),
+                                                  rows))
+        out = out.astype(x.dtype)
+        if "shared" in p_moe:
+            out = out + swiglu(p_moe["shared"], xt)
+        return out.reshape(B, S, d), aux
     router, bank = p_moe["router"], p_moe["experts"]
     axis = par.model_axis
 
@@ -623,8 +640,10 @@ def init_decode_state(cfg: ModelConfig, batch: int, s_cache: int):
 
 
 def _block_decode(p, cfg: ModelConfig, slot: LayerSlot, par: Parallel, x,
-                  positions, cache, *, cross_kv=None):
-    """One-token decode through a block. Returns (x, new_cache)."""
+                  positions, cache, *, cross_kv=None, rows=None):
+    """One-token decode through a block. Returns (x, new_cache, routed):
+    ``routed`` counts an MoE block's routing of ``rows`` (None else)."""
+    routed = None
     if slot.mixer == "slstm":
         x, new = slstm_block_step(p["mixer"], cfg, x, cache)
     else:
@@ -678,21 +697,33 @@ def _block_decode(p, cfg: ModelConfig, slot: LayerSlot, par: Parallel, x,
     if slot.ffn == "dense":
         x = x + swiglu(p["ffn"], rmsnorm(p["norm2"], x, cfg.norm_eps))
     elif slot.ffn == "moe":
-        y, _ = _moe_apply(p["ffn"], cfg, par,
-                          rmsnorm(p["norm2"], x, cfg.norm_eps), decode=True)
+        y, aux = _moe_apply(p["ffn"], cfg, par,
+                            rmsnorm(p["norm2"], x, cfg.norm_eps), decode=True,
+                            rows=rows)
+        routed = aux.get("routed")
         x = x + y
-    return x, new
+    return x, new, routed
 
 
 def decode_step(params, cfg: ModelConfig, par: Parallel, state, token_ids, *,
-                impl=None):
+                impl=None, count_rows=None):
     """serve_step: one new token per sequence against the cache.
 
-    token_ids: (B, 1) int32. Returns (new_state, logits (B, V))."""
+    token_ids: (B, 1) int32. Returns (new_state, logits (B, V)).  With
+    ``count_rows`` (an MoE config's int32 count of leading real rows) it
+    returns (new_state, logits, routed): int32 (3,) sums over MoE layers
+    of those rows' assignments, the assignments to held experts, and the
+    held experts they hit (:func:`moe.routing_counts`)."""
     params = cast_params(params, cfg)
     prefix_slots, n_periods, suffix_slots = _layer_plan(cfg)
     B = token_ids.shape[0]
     positions = state["pos"].reshape(B, 1)
+    rows = None if count_rows is None else jnp.arange(B) < count_rows
+    routed = None if rows is None else jnp.zeros((3,), jnp.int32)
+
+    def count(total, r):
+        return total if r is None else total + r
+
     h = _embed(params, cfg, token_ids)
     if cfg.is_encoder_decoder:
         # decoder learned positions (clipped to table)
@@ -706,31 +737,34 @@ def decode_step(params, cfg: ModelConfig, par: Parallel, state, token_ids, *,
     new_prefix = []
     for p_blk, slot, cache in zip(params["prefix"], prefix_slots,
                                   state["prefix"]):
-        h, new = _block_decode(p_blk, cfg, slot, par, h, positions, cache,
-                               cross_kv=cross_kv)
+        h, new, r = _block_decode(p_blk, cfg, slot, par, h, positions, cache,
+                                  cross_kv=cross_kv, rows=rows)
+        routed = count(routed, r)
         new_prefix.append(new)
     new_state["prefix"] = tuple(new_prefix)
 
     if n_periods:
-        def period_fn(x, xs):
+        def period_fn(carry, xs):
+            x, total = carry
             stacked_p, stacked_c = xs
             new_caches = []
             for j, slot in enumerate(cfg.pattern):
-                x, nc = _block_decode(stacked_p[j], cfg, slot, par, x,
-                                      positions, stacked_c[j],
-                                      cross_kv=cross_kv)
+                x, nc, r = _block_decode(stacked_p[j], cfg, slot, par, x,
+                                         positions, stacked_c[j],
+                                         cross_kv=cross_kv, rows=rows)
+                total = count(total, r)
                 new_caches.append(nc)
-            return x, tuple(new_caches)
+            return (x, total), tuple(new_caches)
 
         if cfg.scan_layers:
-            h, new_scan = jax.lax.scan(period_fn, h,
-                                       (params["scan"], state["scan"]))
+            (h, routed), new_scan = jax.lax.scan(
+                period_fn, (h, routed), (params["scan"], state["scan"]))
         else:
             percall = []
             for i in range(n_periods):
                 xs_i = jax.tree_util.tree_map(
                     lambda a: a[i], (params["scan"], state["scan"]))
-                h, nc = period_fn(h, xs_i)
+                (h, routed), nc = period_fn((h, routed), xs_i)
                 percall.append(nc)
             new_scan = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *percall)
         new_state["scan"] = new_scan
@@ -740,8 +774,9 @@ def decode_step(params, cfg: ModelConfig, par: Parallel, state, token_ids, *,
     new_suffix = []
     for p_blk, slot, cache in zip(params["suffix"], suffix_slots,
                                   state["suffix"]):
-        h, new = _block_decode(p_blk, cfg, slot, par, h, positions, cache,
-                               cross_kv=cross_kv)
+        h, new, r = _block_decode(p_blk, cfg, slot, par, h, positions, cache,
+                                  cross_kv=cross_kv, rows=rows)
+        routed = count(routed, r)
         new_suffix.append(new)
     new_state["suffix"] = tuple(new_suffix)
 
@@ -752,6 +787,8 @@ def decode_step(params, cfg: ModelConfig, par: Parallel, state, token_ids, *,
         logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
     if par.mesh is not None:
         logits = constrain(par, logits, P(par.batch_axes, par.model_axis))
+    if routed is not None:
+        return new_state, logits, routed
     return new_state, logits
 
 

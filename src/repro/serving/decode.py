@@ -137,11 +137,28 @@ class DecodeEngine:
         self.max_batch = int(max_batch)
         self.rng = np.random.default_rng(seed)
 
-        def serve_step(params, state, tokens):
-            state, logits = T.decode_step(params, self.cfg, self.par,
-                                          state, tokens)
-            return state, jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        # an MoE step also carries the routing counts of its real rows
+        # (bucket padding excluded) in and out: no dispatch of its own.
+        # Both steps are named serve_step: the trace's `jit_serve_step`
+        # is what the decode-step roofline reads.
+        self._moe_counts = None
+        if self.cfg.is_moe:
+            def serve_step(params, state, tokens, counts, rows):
+                state, logits, routed = T.decode_step(
+                    params, self.cfg, self.par, state, tokens,
+                    count_rows=rows)
+                return (state,
+                        jnp.argmax(logits, -1)[:, None].astype(jnp.int32),
+                        counts + routed)
 
+            self._moe_counts = jnp.zeros((3,), jnp.int32)
+            self._rows = [jnp.int32(k) for k in range(self.max_batch + 1)]
+        else:
+            def serve_step(params, state, tokens):
+                state, logits = T.decode_step(params, self.cfg, self.par,
+                                              state, tokens)
+                return (state,
+                        jnp.argmax(logits, -1)[:, None].astype(jnp.int32))
         self._step = jax.jit(serve_step)
         self._stack = jax.jit(serve_stack)
         self._unstack = jax.jit(serve_unstack)
@@ -172,6 +189,37 @@ class DecodeEngine:
         return 1 << max(n - 1, 0).bit_length()
 
     # -- the measured lockstep step ---------------------------------------
+    def _run(self, state, tokens, n: int, *, count: bool = False):
+        """One step over a stacked micro-batch of ``n`` real rows: (state,
+        tokens).  An MoE step adds the rows' routing to the counts when
+        ``count``; a warm-up step leaves them as they were."""
+        if self._moe_counts is None:
+            return self._step(self.params, state, tokens)
+        state, tokens, counts = self._step(self.params, state, tokens,
+                                           self._moe_counts, self._rows[n])
+        if count:
+            self._moe_counts = counts
+        return state, tokens
+
+    def moe_counts(self) -> dict | None:
+        """The routing counts of every counted step so far (None for a
+        config without MoE), read with one ``device_get`` and published
+        as telemetry counters: ``moe.routed_assignments`` (real tokens x
+        top-k, over MoE layers), ``moe.held_assignments`` (those to the
+        experts held here) and ``moe.held_experts_hit`` (held experts
+        with at least one token, per step run and layer)."""
+        if self._moe_counts is None:
+            return None
+        routed, held, hit = (int(v) for v in
+                             jax.device_get(self._moe_counts))
+        counts = {"moe.routed_assignments": routed,
+                  "moe.held_assignments": held,
+                  "moe.held_experts_hit": hit}
+        if telemetry.enabled():
+            for name, value in counts.items():
+                telemetry.metrics().counter(name).set(value)
+        return counts
+
     def decode_batch(self, seq_kvs: list, *, work: int = 1) -> float:
         """One decode step for every sequence in ``seq_kvs`` (mutated in
         place with updated state/token); returns the *measured* seconds
@@ -193,9 +241,9 @@ class DecodeEngine:
                 state, tokens = self._stack(
                     [kv.state for kv in chunk] + [self._pad_state] * pad,
                     [kv.token for kv in chunk] + [self._pad_token] * pad)
-                if bucket not in self._warm:   # compile untimed
+                if bucket not in self._warm:   # compile untimed, uncounted
                     jax.block_until_ready(self._unstack(
-                        *self._step(self.params, state, tokens)))
+                        *self._run(state, tokens, len(chunk))))
                     self._warm.add(bucket)
                     telemetry.inc("serve.batch_programs_built")
                 prepared.append((chunk, state, tokens))
@@ -205,9 +253,9 @@ class DecodeEngine:
         with telemetry.span("serve.decode_batch", seqs=n, work=work):
             t0 = time.perf_counter()
             outs = []
-            for _, state, tokens in prepared:
+            for chunk, state, tokens in prepared:
                 for _ in range(max(int(work), 1)):
-                    out = self._step(self.params, state, tokens)
+                    out = self._run(state, tokens, len(chunk), count=True)
                 outs.append(out)
             jax.block_until_ready(outs)
             dt = time.perf_counter() - t0
