@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Chip smoke test: drive the system's main paths once on a TPU.
 
-    python chip_smoke.py [--seed N]       # one chip: three phases
+    python chip_smoke.py [--seed N]       # one chip: four phases
     python chip_smoke.py --chips 4 [...]  # four chips: the mesh phase only
 
 One chip runs, in one process:
@@ -20,6 +20,9 @@ One chip runs, in one process:
   its published widths with bf16 parameters, 4 replicas on the chip,
   KV migration windows on the device transport; it must lose no
   sequence, complete at least one, and keep migrated KV on the device.
+* ``codec_parity`` — the compiled pack kernels bit for bit against the
+  XLA oracles at the serving cells' KV rows and the relocation cell's
+  records, with each kernel's best wall time.
 
 ``--chips 4`` runs the SPMD steal loop and ``spmd_team_reduce`` under
 ``shard_map`` on a 4-chip mesh (one place per chip) and checks both
@@ -200,6 +203,67 @@ def serving(seed: int, *, cfg=None, s_cache: int = 2048, max_batch: int = 8,
             "pad_waste_bytes": life.pad_waste_bytes,
             "exchanges": life.exchanges,
             "kv_leaf_device": str(leaf[0].devices().pop())}
+
+
+# KV word rows of the serving cells (cache words, then ``pos`` and
+# ``token``): name, words a row, width class, rows back to back
+KV_ROWS = (("qwen2_1_5b_kv", 28 * 2 * 2 * 128 * 2048 // 2 + 2, 1 << 26, 2),
+           ("deepseek_v2_lite_kv", 14 * 2048 * (576 * 2 + 4) // 4 + 2,
+            1 << 25, 3))
+
+
+def codec_parity(seed: int, *, kv_rows=KV_ROWS, records: int = 32768,
+                 floats: int = 250, pairs: int = 64, slots: int = 512,
+                 reps: int = 5) -> dict:
+    """The compiled pack kernels against the XLA oracles of
+    ``kernels/ref.py``, bit for bit, at the serving cells' KV rows (back
+    to back in one arena, so all but the first start past a chunk
+    boundary) and the relocation cell's records (1000 B float32 rows in
+    the 1024 B class, about half the slots live).  Each kernel's best
+    wall time of ``reps`` is reported."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import ops, ref
+
+    def check(name, run, oracle):
+        got = jax.block_until_ready(run())
+        assert bool(jnp.array_equal(got, oracle())), \
+            f"{name}: the pack differs from the oracle"
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run())
+            best = min(best, time.perf_counter() - t0)
+        return {"ms": best * 1e3}
+
+    key = jax.random.key(seed)
+    out = {}
+    for i, (name, n, width, count) in enumerate(kv_rows):
+        words = jax.random.bits(jax.random.fold_in(key, i), (count * n,),
+                                jnp.uint32)
+        offs = np.arange(count, dtype=np.int32) * n
+        wids = np.full(count, 4 * n, np.int32)
+        kw = dict(pairs=count, slots=1, width=width)
+        out[name] = check(
+            name, lambda: ops.reloc_pack_rows(words, offs, wids, **kw),
+            lambda: ref.reloc_pack_rows_ref(words, offs, wids, **kw))
+        out[name].update(rows=count, row_words=n)
+        del words
+    rng = np.random.default_rng(seed)
+    mat = jax.random.normal(jax.random.fold_in(key, len(kv_rows)),
+                            (records, floats), jnp.float32)
+    idx = rng.integers(0, records, pairs * slots).astype(np.int32)
+    wid = np.where(rng.random(pairs * slots) < 0.5, 4 * floats,
+                   0).astype(np.int32)
+    kw = dict(pairs=pairs, slots=slots, width=1024)
+    out["relocation_records"] = check(
+        "relocation_records",
+        lambda: ops.reloc_encode_pack(mat, idx, wid, **kw),
+        lambda: ref.reloc_encode_pack_ref(mat, idx, wid, **kw))
+    out["relocation_records"].update(live=int(np.count_nonzero(wid)),
+                                     slots=pairs * slots)
+    return out
 
 
 class _SumReducer:
@@ -426,7 +490,8 @@ def main(argv=None) -> int:
     else:
         phases = [("relocation", lambda: relocation(seed)),
                   ("glb_device_loop", lambda: glb_device_loop(seed)),
-                  ("serving", lambda: serving(seed))]
+                  ("serving", lambda: serving(seed)),
+                  ("codec_parity", lambda: codec_parity(seed))]
     ok = all([run_phase(name, fn, compiles, devices[0])
               for name, fn in phases])
     if not ok:

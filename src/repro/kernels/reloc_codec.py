@@ -28,14 +28,24 @@ RL009):
   arena at any word offset; each live slot receives its row's words,
   zeroed past the row's width.  The arena stays in HBM as ``(R, 1,
   128)`` super-rows — the finest granularity the TPU's DMA engine
-  addresses — and each output chunk is fetched as two super-rows and
-  realigned in VMEM with a dynamic lane rotate.  Rows narrower than a
-  chunk share one, rows wider span several.  Empty slots are never
-  written: the output aliases a zero buffer.
+  addresses.  A wide row moves in blocks of up to :data:`_BLOCK`
+  chunks, the remainder in power-of-two pieces (DMA sizes are static):
+  each block is fetched with one more super-row into VMEM, realigned
+  with a dynamic lane rotate and sent on in one copy, the next block's
+  fetch in flight meanwhile.  The words past the row's last live one
+  (the next row's) are masked off in the rotate, so they stay off the
+  wire.  Rows narrower than a chunk share one, each fetched as two
+  super-rows.  Empty slots are never written: the output aliases a
+  zero buffer.
 * :func:`encode_pack` — the same gather for a typed chunk matrix: its
   word rows (padded to the row class) are the arena.
 * :func:`decode_rows` — received word rows → the manifest's dtype: trim
   the class padding and reinterpret, in row tiles.
+
+Both packing entry points open a ``codec.pack`` telemetry span that
+counts the live wide rows starting on a chunk boundary
+(``rows_aligned``) and the others (``rows_rotated``), where the slot
+tables are host arrays.
 
 All kernels run under ``interpret=True`` on CPU (the parity target,
 bit-identical to :mod:`repro.kernels.ref`); the compiled path is the
@@ -230,7 +240,49 @@ def _arena_rows(n_words_: int, wq: int) -> int:
 # ---------------------------------------------------------------------------
 # the slot gather: word arena -> (pairs, chunks, 1, 128) send buffer
 # ---------------------------------------------------------------------------
-def _gather_kernel(off_ref, len_ref, arena, _zeros, out, scr, ob, sem, *,
+_BLOCK = 256        # chunks one block moves (a power of two)
+_GROUP = 8          # super-rows rotated per vector step (a power of two)
+
+
+def _rotate_into(src, dst, n: int, c, keep):
+    """``dst[j]`` = words ``[c, c + 128)`` of super-rows ``src[j],
+    src[j + 1]``, for ``j < n``: the chunks of a row that starts at lane
+    ``c`` (``src`` holds ``n + 1`` super-rows).  Words from ``keep`` on
+    (counted from ``dst[0]``'s first) are zeroed: past the row's end
+    they are the next row's."""
+    g = min(_GROUP, n)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (g, 1, _LANES), 2)
+    word = jax.lax.broadcasted_iota(jnp.int32, (g, 1, _LANES), 0) \
+        * _LANES + lane
+    shift = (_LANES - c) % _LANES
+
+    def step(i, carry):
+        a = pltpu.roll(src[pl.ds(i * g, g)], shift, 2)
+        b = pltpu.roll(src[pl.ds(i * g + 1, g)], shift, 2)
+        y = jnp.where(lane < _LANES - c, a, b)
+        dst[pl.ds(i * g, g)] = jnp.where(word < keep - i * g * _LANES, y,
+                                         jnp.uint32(0))
+        return carry
+
+    jax.lax.fori_loop(0, n // g, step, 0)
+
+
+def _block(wq: int) -> int:
+    """Chunks a block moves in the ``wq``-word class: no more than a
+    slot holds."""
+    return min(_BLOCK, wq // _LANES)
+
+
+def _pieces(rem, at0, block: int):
+    """Split ``rem`` (< ``block``) chunks from chunk ``at0`` into static
+    power-of-two sizes, largest first: ``(size, start, taken)``."""
+    b = block // 2
+    while b:
+        yield b, at0 + (rem & ~(2 * b - 1)), (rem & b) != 0
+        b //= 2
+
+
+def _gather_kernel(off_ref, len_ref, arena, _zeros, out, *scratch,
                    slots: int, wq: int):
     """One grid step packs one (src, dest) pair's ``slots`` rows.
 
@@ -238,9 +290,89 @@ def _gather_kernel(off_ref, len_ref, arena, _zeros, out, scr, ob, sem, *,
     live word count of each slot's row in ``arena`` (HBM super-rows).
     ``out`` (HBM) aliases a zero buffer, so only chunks holding live
     words are written."""
+    if wq < _LANES:
+        _narrow(off_ref, len_ref, arena, out, *scratch, slots=slots, wq=wq)
+        return
+    p = pl.program_id(0)
+    blk, rot, sem = scratch
+    nsub = wq // _LANES
+    B = _block(wq)
+
+    def row(off, n, r):
+        # every read stays inside the arena, whatever the offset
+        s0 = jnp.minimum(off // _LANES, arena.shape[0] - nsub - 2)
+        c = off % _LANES
+        nc = (n + _LANES - 1) // _LANES     # chunks holding live words
+        nb = nc // B
+
+        def fetch(at, s, size=B):
+            return pltpu.make_async_copy(
+                arena.at[pl.ds(s0 + at, size + 1)],
+                blk.at[s, pl.ds(0, size + 1)], sem.at[0, s])
+
+        def emit(at, s, size=B):
+            return pltpu.make_async_copy(
+                rot.at[s, pl.ds(0, size)],
+                out.at[p, pl.ds(r * nsub + at, size)], sem.at[1, s])
+
+        def rotate(at, s, size=B):
+            _rotate_into(blk.at[s], rot.at[s], size, c, n - at * _LANES)
+
+        @pl.when(nb > 0)
+        def _():
+            fetch(0, 0).start()
+
+        def block(i, carry):
+            # block i + 1 is fetched while block i is rotated and sent
+            s = i % 2
+
+            @pl.when(i + 1 < nb)
+            def _():
+                fetch((i + 1) * B, 1 - s).start()
+
+            fetch(i * B, s).wait()
+
+            @pl.when(i >= 2)
+            def _():
+                emit((i - 2) * B, s).wait()
+
+            rotate(i * B, s)
+            emit(i * B, s).start()
+            return carry
+
+        def drain(i, carry):
+            emit(i * B, i % 2).wait()
+            return carry
+
+        jax.lax.fori_loop(0, nb, block, 0)
+        jax.lax.fori_loop(jnp.maximum(nb - 2, 0), nb, drain, 0)
+        for b, at, taken in _pieces(nc % B, nb * B, B):
+            @pl.when(taken)
+            def _():
+                f = fetch(at, 0, b)
+                f.start()
+                f.wait()
+                rotate(at, 0, b)
+                e = emit(at, 0, b)
+                e.start()
+                e.wait()
+
+    def slot(r, carry):
+        n = jnp.minimum(len_ref[0, 0, r], wq)
+        pl.when(n > 0)(lambda: row(off_ref[0, 0, r], n, r))
+        return carry
+
+    jax.lax.fori_loop(0, slots, slot, 0)
+
+
+def _narrow(off_ref, len_ref, arena, out, scr, ob, sem, *, slots: int,
+            wq: int):
+    """Rows narrower than a chunk: ``128 // wq`` of them share one, each
+    fetched as two super-rows."""
     p = pl.program_id(0)
     rows = arena.shape[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
+    per = min(_LANES // wq, slots)
 
     def fetch(q, keep):
         # arena words [q, q + 128): two super-rows, rotated so word q
@@ -255,32 +387,6 @@ def _gather_kernel(off_ref, len_ref, arena, _zeros, out, scr, ob, sem, *,
                       pltpu.roll(scr[1], shift, 1))
         return jnp.where(lane < keep, y, jnp.uint32(0))
 
-    def emit(chunk):
-        cp = pltpu.make_async_copy(ob, out.at[p, pl.ds(chunk, 1)], sem)
-        cp.start()
-        cp.wait()
-
-    if wq >= _LANES:
-        nsub = wq // _LANES
-
-        def slot(r, carry):
-            off = off_ref[0, 0, r]
-            n = jnp.minimum(len_ref[0, 0, r], wq)
-
-            def sub(j, carry):
-                @pl.when(n > j * _LANES)
-                def _():
-                    ob[0] = fetch(off + j * _LANES, n - j * _LANES)
-                    emit(r * nsub + j)
-                return carry
-
-            return jax.lax.fori_loop(0, nsub, sub, carry)
-
-        jax.lax.fori_loop(0, slots, slot, 0)
-        return
-
-    per = min(_LANES // wq, slots)      # rows sharing one output chunk
-
     def group(g, carry):
         def one(t, acc):
             r = g * per + t
@@ -292,7 +398,9 @@ def _gather_kernel(off_ref, len_ref, arena, _zeros, out, scr, ob, sem, *,
 
         ob[0] = jax.lax.fori_loop(0, per, one,
                                   jnp.zeros((1, _LANES), jnp.uint32))
-        emit(g)
+        cp = pltpu.make_async_copy(ob, out.at[p, pl.ds(g, 1)], sem)
+        cp.start()
+        cp.wait()
         return carry
 
     jax.lax.fori_loop(0, slots // per, group, 0)
@@ -307,19 +415,29 @@ def _gather_call(pairs: int, slots: int, width: int, rows: int,
     if fn is not None:
         return fn
     chunks = _chunks(slots, width)
+    wq = width // 4
     tab = pl.BlockSpec((1, 1, slots), lambda p: (p, 0, 0),
                        memory_space=pltpu.SMEM)
+    if wq < _LANES:
+        scratch = [pltpu.VMEM((2, 1, _LANES), jnp.uint32),
+                   pltpu.VMEM((1, 1, _LANES), jnp.uint32),
+                   pltpu.SemaphoreType.DMA(())]
+    else:
+        # two blocks in turn; a (1, 128) super-row fills an (8, 128)
+        # tile of VMEM: 4 KiB
+        block = _block(wq)
+        scratch = [pltpu.VMEM((2, block + 1, 1, _LANES), jnp.uint32),
+                   pltpu.VMEM((2, block, 1, _LANES), jnp.uint32),
+                   pltpu.SemaphoreType.DMA((2, 2))]
     call = pl.pallas_call(
-        functools.partial(_gather_kernel, slots=slots, wq=width // 4),
+        functools.partial(_gather_kernel, slots=slots, wq=wq),
         grid=(pairs,),
         in_specs=[tab, tab, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((pairs, chunks, 1, _LANES),
                                        jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((2, 1, _LANES), jnp.uint32),
-                        pltpu.VMEM((1, 1, _LANES), jnp.uint32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch,
         input_output_aliases={3: 0},
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
@@ -361,8 +479,28 @@ def pack_rows(words, offsets, widths, *, pairs: int, slots: int,
     words = jnp.asarray(words, jnp.uint32)
     fn = _pack_rows_call(pairs, slots, width, int(words.shape[0]),
                          interpret)
-    return fn(words, jnp.asarray(offsets, jnp.int32),
-              jnp.asarray(widths, jnp.int32))
+    from ..core import telemetry   # core imports this module
+
+    with telemetry.span("codec.pack") as sp:
+        if sp:
+            _count_rows(sp, offsets, widths, width)
+        return fn(words, jnp.asarray(offsets, jnp.int32),
+                  jnp.asarray(widths, jnp.int32))
+
+
+def _count_rows(sp, offsets, widths, width: int) -> None:
+    """Stamp ``rows_aligned`` / ``rows_rotated`` on a ``codec.pack``
+    span: the live wide-class slots whose word offset is, or is not, a
+    multiple of 128 (a row that starts on a chunk boundary rotates by
+    no lanes).  Tables that live on the device are not counted: reading
+    them would wait for the device; nor are rows narrower than a chunk,
+    which share one."""
+    if (width // 4 >= _LANES and isinstance(offsets, np.ndarray)
+            and isinstance(widths, np.ndarray)):
+        live = widths > 0
+        aligned = int(np.count_nonzero(offsets[live] % _LANES == 0))
+        sp.set(rows_aligned=aligned,
+               rows_rotated=int(np.count_nonzero(live)) - aligned)
 
 
 def _pack_rows_call(pairs: int, slots: int, width: int, n: int,
@@ -424,8 +562,14 @@ def encode_pack(mat, idx, widths, *, pairs: int, slots: int, width: int,
     m, k = int(mat.shape[0]), int(mat.shape[1])
     fn = _encode_pack_call(pairs, slots, width, m, k, mat.dtype,
                            interpret)
-    return fn(mat, jnp.asarray(idx, jnp.int32),
-              jnp.asarray(widths, jnp.int32))
+    from ..core import telemetry
+
+    with telemetry.span("codec.pack") as sp:
+        if sp and isinstance(idx, np.ndarray):
+            _count_rows(sp, np.clip(idx, 0, m - 1) * (width // 4), widths,
+                        width)
+        return fn(mat, jnp.asarray(idx, jnp.int32),
+                  jnp.asarray(widths, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

@@ -119,6 +119,158 @@ class TestKernelParity:
                                          "pallas_interpret", "xla_naive")
 
 
+# ---------------------------------------------------------------------------
+# the block gather of wide rows: small blocks, so rows stay small here
+# ---------------------------------------------------------------------------
+BLOCK, GROUP = 8, 2     # chunks a block moves; super-rows a rotate step
+ROW_WORDS = {
+    "one_block": 8 * 128,
+    "block_and_chunk": 9 * 128,
+    "every_remainder_bit": 15 * 128,         # 1 block + 4 + 2 + 1 chunks
+    "blocks_bits_and_tail": 29 * 128 + 37,   # 3 blocks (past 2 buffers),
+                                             # 4 + 1 chunks and a tail
+    "block_ending_in_tail": 7 * 128 + 37,    # the tail in the last step
+    "tail_only": 37,
+}
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    from repro.kernels import reloc_codec as rc
+
+    monkeypatch.setattr(rc, "_BLOCK", BLOCK)
+    monkeypatch.setattr(rc, "_GROUP", GROUP)
+    monkeypatch.setattr(rc, "_CACHE", LRUCache(64))   # no stale programs
+
+
+def _pack_both(words, offs, wids, *, pairs, slots, width):
+    got = np.asarray(ops.reloc_pack_rows(
+        words, offs, wids, pairs=pairs, slots=slots, width=width,
+        impl="pallas_interpret"))
+    want = np.asarray(ref.reloc_pack_rows_ref(
+        words, offs, wids, pairs=pairs, slots=slots, width=width))
+    return got, want
+
+
+class TestBlockGather:
+    @pytest.mark.parametrize("length", sorted(ROW_WORDS))
+    @pytest.mark.parametrize("lane", [0, 2, 127])
+    def test_pack_rows_matches_ref(self, small_blocks, lane, length):
+        # pair 0: two rows back to back, so the first row's tail chunk
+        # holds its neighbour's words, which must not leak onto the wire;
+        # pair 1: an empty slot, then a row ending at the arena's end;
+        # pairs 2 and 3 are empty
+        n, W = ROW_WORDS[length], 16384             # 32 chunks a slot
+        rng = np.random.default_rng(lane * 31 + n)
+        words = rng.integers(1, 2**32, lane + 3 * n + 5,
+                             dtype=np.uint64).astype(np.uint32)
+        offs = np.array([lane, lane + n, 0, len(words) - n, 0, 0, 0, 0],
+                        np.int32)
+        wids = np.array([4 * n, 4 * n, 0, 4 * n - 3, 0, 0, 0, 0], np.int32)
+        got, want = _pack_both(words, offs, wids, pairs=4, slots=2, width=W)
+        assert np.array_equal(got, want)
+        rows = wire_rows(got, 2, W)
+        assert np.array_equal(rows[0, 0, :n], words[lane:lane + n])
+        assert not rows[0, 0, n:].any()       # nothing past the row
+        assert not rows[1, 0].any() and not rows[2:].any()
+
+    @pytest.mark.parametrize("lane", [0, 2, 127])
+    def test_chunk_class_boundary(self, small_blocks, lane):
+        # the 512-byte class: one chunk a slot, whole or partial rows
+        words = np.arange(1, 8 * 128, dtype=np.uint32)
+        offs = np.array([lane, lane + 128, lane + 256, 0], np.int32)
+        wids = np.array([512, 200, 512, 0], np.int32)
+        got, want = _pack_both(words, offs, wids, pairs=2, slots=2,
+                               width=512)
+        assert np.array_equal(got, want)
+        assert not got[1, 1].any()
+
+    @pytest.mark.parametrize("short", [False, True])
+    def test_encode_pack_relocation_shape(self, small_blocks, short):
+        # 1000-byte float32 rows in the 1024-byte class: a row's two
+        # chunks move as one block, masked past its 250 words, or past
+        # fewer where a row is declared shorter than its words
+        rng = np.random.default_rng(5)
+        mat = rng.standard_normal((12, 250)).astype(np.float32)
+        idx = rng.integers(0, 12, 4 * 8).astype(np.int32)
+        wid = np.where(rng.random(4 * 8) < 0.7, 1000, 0).astype(np.int32)
+        wid[:8] = 0                                    # an empty pair
+        if short:
+            wid[wid > 0] = 990
+        got = np.asarray(ops.reloc_encode_pack(
+            mat, idx, wid, pairs=4, slots=8, width=1024,
+            impl="pallas_interpret"))
+        want = np.asarray(ref.reloc_encode_pack_ref(
+            mat, idx, wid, pairs=4, slots=8, width=1024))
+        assert np.array_equal(got, want)
+        assert not got[0].any()
+
+    def test_codec_pack_span_counts_aligned_and_rotated_rows(self, fused):
+        # two SeqKV rows (cache words + pos + token) of one pair: the
+        # first starts the arena, the second two words past a chunk
+        import jax
+        from repro.serving.cache import SeqKV
+
+        def run():
+            g = PlaceGroup(2)
+            m = DistIdMap(g)
+            for p in g.members:
+                m.handle(p)
+            for k in range(2):
+                cache = {"k": jax.device_put(np.full((16, 4), k, np.float32)),
+                         "v": jax.device_put(np.full((16, 4), -k, np.float32)),
+                         "pos": jax.device_put(np.full((1,), k, np.int32))}
+                m.put(0, k, SeqKV(cache, jax.device_put(
+                    np.full((1, 1), k + 7, np.int32))))
+            mm = CollectiveMoveManager(g, transport="device")
+            m.move_at_sync(0, lambda k: 1, mm)
+            mm.sync()
+            return [jax.tree_util.tree_map(
+                lambda x: np.asarray(x).tobytes(), m.get(1, k).state)
+                for k in range(2)]
+
+        telemetry.enable()
+        try:
+            telemetry.tracer().clear()
+            got = run()
+            packs = [r for r in telemetry.tracer().records()
+                     if r["name"] == "codec.pack"]
+        finally:
+            telemetry.reset()
+            telemetry.disable()
+        assert len(packs) == 1
+        assert packs[0]["args"]["rows_aligned"] == 1
+        assert packs[0]["args"]["rows_rotated"] == 1
+        with backend("xla"):
+            assert run() == got
+
+    @pytest.mark.parametrize("width,counts", [
+        (64, None),             # rows share a chunk: nothing to count
+        (1024, (2, 1)),
+        (8192, (2, 1)),
+    ])
+    def test_codec_pack_span_counts_host_tables(self, width, counts):
+        words = np.arange(1, 2049, dtype=np.uint32)
+        offs = np.array([0, 7, 256, 0], np.int32)
+        wids = np.array([40, 40, 40, 0], np.int32)
+        telemetry.enable()
+        try:
+            telemetry.tracer().clear()
+            got = np.asarray(ops.reloc_pack_rows(
+                words, offs, wids, pairs=2, slots=2, width=width,
+                impl="pallas_interpret"))
+            (pack,) = [r for r in telemetry.tracer().records()
+                       if r["name"] == "codec.pack"]
+        finally:
+            telemetry.reset()
+            telemetry.disable()
+        assert np.array_equal(got, np.asarray(ref.reloc_pack_rows_ref(
+            words, offs, wids, pairs=2, slots=2, width=width)))
+        args = pack.get("args") or {}
+        assert (args.get("rows_aligned"), args.get("rows_rotated")) == (
+            counts or (None, None))
+
+
 @settings(max_examples=20, deadline=None)
 @given(m=st.integers(1, 10), k=st.integers(1, 6), dt=st.integers(0, 2),
        extra=st.integers(0, 2))
