@@ -2,8 +2,6 @@
 sharded data collection, AdamW, checkpointing, straggler mitigation.
 
 CPU-sized by default (~1M params, 60 steps); pass --steps/--dim to grow.
-On a real cluster the same script runs under the production mesh via
-repro.launch.mesh.make_production_mesh().
 """
 import argparse
 import sys

@@ -43,7 +43,7 @@ RL009  direct ``pl.pallas_call`` outside ``kernels/``: kernels must
        register in ``kernels.ops``'s backend dispatch so the
        interpret-mode CPU fallback and the XLA reference path are
        never bypassed — a raw ``pallas_call`` in data-plane code
-       breaks CPU CI and dry-run cost analysis silently.
+       breaks CPU CI silently.
 
 Suppression: add ``# noqa`` (optionally ``# noqa: RL00x``) or
 ``# repro-lint: ok`` on the flagged line.

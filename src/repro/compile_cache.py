@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
-``chip_smoke.py`` and ``benchmarks/run.py`` call :func:`enable` before
+``chip_smoke.py`` and ``bench/harness.py`` call :func:`enable` before
 their first compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 itself reads it and this module sets no other directory; otherwise the
 cache lives at the fixed path ``<repo>/.jax_cache`` (a moving path

@@ -1,16 +1,13 @@
-"""Assigned architecture configs (10) + shape cells.
+"""Assigned architecture configs (10).
 
 Each module exposes ``CONFIG`` (exact pool spec) — retrieve via
-``get_config(name)``; ``SHAPES`` defines the four assigned input-shape
-cells and ``cells_for(config)`` applies the per-family skip rules
-(see DESIGN.md §Arch-applicability).
+``get_config(name)``.
 """
 from __future__ import annotations
 
 import importlib
 
 from ..models.config import ModelConfig
-from ..shapes import SHAPES, ShapeCell
 
 ARCH_IDS = [
     "qwen2_1_5b",
@@ -31,16 +28,3 @@ def get_config(name: str) -> ModelConfig:
         raise KeyError(f"unknown arch {name!r}; known: {ARCH_IDS}")
     mod = importlib.import_module(f".{key}", __package__)
     return mod.CONFIG
-
-
-def cells_for(cfg: ModelConfig) -> list[tuple[str, str]]:
-    """(shape_name, status) pairs; status 'run' or skip reason."""
-    out = []
-    for cell in SHAPES:
-        if cell.name == "long_500k" and cfg.pure_full_attention:
-            out.append((cell.name, "skip: full-attention long-context"))
-        elif cell.name == "long_500k" and cfg.is_encoder_decoder:
-            out.append((cell.name, "skip: enc-dec has no 500k context"))
-        else:
-            out.append((cell.name, "run"))
-    return out

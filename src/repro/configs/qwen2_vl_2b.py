@@ -1,8 +1,8 @@
 """qwen2-vl-2b [vlm] — M-RoPE, dynamic resolution [arXiv:2409.12191; hf].
 
-Backbone only; the vision frontend is a STUB — ``input_specs`` provides
-token ids plus the (3, B, S) M-RoPE position streams that precomputed
-patch embeddings would induce.
+Backbone only; the vision frontend is a STUB — the model takes token
+ids plus the (3, B, S) M-RoPE position streams that precomputed patch
+embeddings would induce.
 """
 from ..models.config import LayerSlot, ModelConfig
 

@@ -1,8 +1,8 @@
 """whisper-small [audio] — enc-dec, conv frontend (stub)
 [arXiv:2212.04356; unverified].
 
-``input_specs`` provides precomputed frame embeddings (B, S, d) in place
-of the log-mel conv frontend.
+The model takes precomputed frame embeddings (B, S, d), the batch's
+``enc_frames``, in place of the log-mel conv frontend.
 """
 from ..models.config import LayerSlot, ModelConfig
 

@@ -2,10 +2,7 @@
 
 Each op resolves to (a) the Pallas TPU kernel on TPU backends,
 (b) the Pallas kernel in interpret mode when explicitly requested
-(CPU validation), or (c) the pure-jnp reference (XLA path) otherwise —
-the XLA path is what the multi-pod dry-run lowers, keeping
-``cost_analysis`` FLOPs honest while the Pallas kernels remain the TPU
-execution target.
+(CPU validation), or (c) the pure-jnp reference (XLA path) otherwise.
 
 Select with ``repro.kernels.ops.set_backend("xla"|"pallas"|"pallas_interpret")``
 or per-call via ``impl=``; the ``REPRO_KERNEL_BACKEND`` environment

@@ -144,12 +144,6 @@ class ModelConfig:
     def uses_attention(self) -> bool:
         return any(s.mixer.startswith(("attn", "mla")) for s in self.pattern)
 
-    @property
-    def pure_full_attention(self) -> bool:
-        """True if every mixer is global full attention (→ skip long_500k)."""
-        mixers = {s.mixer for s in self.pattern}
-        return mixers <= {"attn_global", "mla"}
-
     def layer_slots(self) -> list[LayerSlot]:
         """Materialized per-layer slot list with first_dense override."""
         out = []

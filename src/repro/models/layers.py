@@ -2,8 +2,8 @@
 
 Parameters are plain nested dicts of jnp arrays; ``init_*`` functions
 return the dict, ``apply`` logic lives alongside.  Everything is
-init-by-closure so the dry-run can obtain shapes with ``jax.eval_shape``
-without allocating.
+init-by-closure so shapes can be obtained with ``jax.eval_shape``
+without allocating (``zoo.abstract_params``).
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@ same block machinery (mixer × ffn slots, scanned over pattern periods).
 
 Layer stacking = prefix (first-dense / remainder-breaking layers,
 unstacked) + ``lax.scan`` over full pattern periods (stacked params →
-small HLO, essential for the 512-device dry-run) + suffix remainder.
+small HLO) + suffix remainder.
 
 Teamed-operation islands (shard_map): MoE expert dispatch
 (= collective relocation), vocab-parallel cross-entropy (= teamed
@@ -793,7 +793,7 @@ def decode_step(params, cfg: ModelConfig, par: Parallel, state, token_ids, *,
 
 
 # ---------------------------------------------------------------------------
-# Parallel prefill (the prefill_* dry-run cells lower this)
+# Parallel prefill
 # ---------------------------------------------------------------------------
 def _fill_attn_cache(cfg: ModelConfig, slot: LayerSlot, kv, positions,
                      s_cache: int):
@@ -854,8 +854,8 @@ def prefill_forward(params, cfg: ModelConfig, par: Parallel, batch,
                     s_cache: int, *, impl=None):
     """Parallel prefill: full forward, returns (decode_state, last_logits).
 
-    This is what the ``prefill_*`` dry-run cells lower — one pass through
-    the parallel kernels, caches/recurrent states assembled for decode.
+    One pass through the parallel kernels, caches/recurrent states
+    assembled for decode.
     """
     params = cast_params(params, cfg)
     tokens = batch["tokens"]

@@ -1,25 +1,13 @@
-"""Public model API: config name → params / step functions / input specs.
-
-``input_specs(cfg, cell, par)`` returns weak-type-correct
-``jax.ShapeDtypeStruct`` stand-ins for every model input of a shape cell
-(train batch, prefill batch, or decode state) — shardable, no device
-allocation — the dry-run contract.
-"""
+"""Public model API: config → params and the training loss."""
 from __future__ import annotations
 
-from functools import partial
-from typing import Any
-
 import jax
-import jax.numpy as jnp
 
-from ..shapes import ShapeCell
 from .config import ModelConfig
 from .parallel import Parallel
 from . import transformer as T
 
-__all__ = ["abstract_params", "init_params", "train_loss_fn", "decode_fn",
-           "prefill_fn", "input_specs", "decode_state_specs"]
+__all__ = ["abstract_params", "init_params", "train_loss_fn"]
 
 
 def init_params(cfg: ModelConfig, seed: int = 0):
@@ -35,155 +23,3 @@ def train_loss_fn(cfg: ModelConfig, par: Parallel, *, impl=None):
     def fn(params, batch):
         return T.train_loss(params, cfg, par, batch, impl=impl)
     return fn
-
-
-def decode_fn(cfg: ModelConfig, par: Parallel, *, impl=None):
-    def fn(params, state, token_ids):
-        return T.decode_step(params, cfg, par, state, token_ids, impl=impl)
-    return fn
-
-
-def prefill_fn(cfg: ModelConfig, par: Parallel, s_cache: int, *, impl=None):
-    def fn(params, batch):
-        return T.prefill_forward(params, cfg, par, batch, s_cache, impl=impl)
-    return fn
-
-
-# ---------------------------------------------------------------------------
-# Input specs (ShapeDtypeStructs)
-# ---------------------------------------------------------------------------
-def _sds(shape, dtype):
-    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-
-
-def _train_batch_specs(cfg: ModelConfig, B: int, S: int):
-    specs: dict[str, Any] = {
-        "tokens": _sds((B, S), jnp.int32),
-        "labels": _sds((B, S), jnp.int32),
-    }
-    if cfg.is_encoder_decoder:
-        # stub audio frontend: precomputed frame embeddings; decoder gets
-        # the (short) target sequence
-        specs["enc_frames"] = _sds((B, S, cfg.d_model), jnp.bfloat16)
-        dec = min(cfg.max_target_len, S)
-        specs["tokens"] = _sds((B, dec), jnp.int32)
-        specs["labels"] = _sds((B, dec), jnp.int32)
-    if cfg.mrope_sections:
-        specs["mrope_positions"] = _sds((3, B, S), jnp.int32)
-    return specs
-
-
-def decode_state_specs(cfg: ModelConfig, B: int, s_cache: int):
-    state = jax.eval_shape(partial(T.init_decode_state, cfg, B, s_cache))
-    if cfg.is_encoder_decoder:
-        state = dict(state)
-        state["cross_kv"] = _sds((B, s_cache, cfg.d_model), jnp.bfloat16)
-    return state
-
-
-def _divisible(n: int, par: Parallel, axes) -> bool:
-    if par is None or par.mesh is None:
-        return False
-    if isinstance(axes, str):
-        axes = (axes,)
-    prod = 1
-    for a in axes:
-        prod *= par.mesh.shape[a]
-    return n % prod == 0 and n >= prod
-
-
-def decode_state_partition_specs(cfg: ModelConfig, par: Parallel, B: int,
-                                 s_cache: int):
-    """PartitionSpec tree matching ``decode_state_specs``.
-
-    Sharding policy (baseline; §Perf iterates on it):
-      * batch dim over the batch axes when divisible;
-      * kv heads over the model axis when divisible, else the cache
-        sequence dim over the model axis;
-      * batch=1 long-context: cache sequence dim over the batch axes
-        (sequence-parallel cache) in addition to heads over model;
-      * recurrent states: rows over batch axes, matrix dim over model.
-    """
-    from jax.sharding import PartitionSpec as P
-    ba = par.batch_axes
-    m = par.model_axis
-    b_ok = _divisible(B, par, ba)
-    b_ax = ba if b_ok else None
-
-    def attn_spec(size, lead):
-        pre = (None,) * lead
-        h_ok = _divisible(cfg.n_kv_heads, par, m)
-        s_model = None if h_ok else (m if _divisible(size, par, m) else None)
-        s_batch = ba if (not b_ok and _divisible(size, par, ba)) else None
-        s_ax = s_batch if s_batch is not None else s_model
-        return {
-            "k": P(*pre, b_ax, s_ax, m if h_ok else None, None),
-            "v": P(*pre, b_ax, s_ax, m if h_ok else None, None),
-            "pos": P(*pre, b_ax, s_ax),
-        }
-
-    def mla_spec(lead):
-        pre = (None,) * lead
-        s_ax = m if _divisible(s_cache, par, m) else None
-        return {
-            "ckv": P(*pre, b_ax, s_ax, None),
-            "krope": P(*pre, b_ax, s_ax, None),
-            "pos": P(*pre, b_ax, s_ax),
-        }
-
-    def rec_spec(slot_mixer, lead):
-        pre = (None,) * lead
-        if slot_mixer == "rec":
-            rec = cfg.rec_dim or cfg.d_model
-            r_ax = m if _divisible(rec, par, m) else None
-            return {"h": P(*pre, b_ax, r_ax),
-                    "conv_tail": P(*pre, b_ax, None, r_ax)}
-        if slot_mixer == "mlstm":
-            H = cfg.rec_heads or 4
-            bh_ok = _divisible(B * H, par, ba)
-            bh = ba if bh_ok else None
-            hd = int(cfg.proj_factor * cfg.d_model) // H
-            h_ax = m if _divisible(hd, par, m) else None
-            return {"C": P(*pre, bh, h_ax, None), "n": P(*pre, bh, h_ax),
-                    "m": P(*pre, bh)}
-        # slstm
-        d_ax = m if _divisible(cfg.d_model, par, m) else None
-        return {"c": P(*pre, b_ax, d_ax), "n": P(*pre, b_ax, d_ax),
-                "m": P(*pre, b_ax, d_ax), "h": P(*pre, b_ax, d_ax)}
-
-    def slot_spec(slot, lead):
-        if slot.mixer in ("attn_global", "attn_local"):
-            size = s_cache if slot.mixer == "attn_global" else min(
-                s_cache, cfg.window or s_cache)
-            return attn_spec(size, lead)
-        if slot.mixer == "mla":
-            return mla_spec(lead)
-        return rec_spec(slot.mixer, lead)
-
-    prefix_slots, n_periods, suffix_slots = T._layer_plan(cfg)
-    specs = {
-        "pos": P(b_ax),
-        "prefix": tuple(slot_spec(s, 0) for s in prefix_slots),
-        "suffix": tuple(slot_spec(s, 0) for s in suffix_slots),
-        "scan": tuple(slot_spec(s, 1) for s in cfg.pattern)
-        if n_periods else (),
-    }
-    if cfg.is_encoder_decoder:
-        enc_ax = m if _divisible(s_cache, par, m) else None
-        specs["cross_kv"] = P(b_ax, enc_ax, None)
-    return specs
-
-
-def input_specs(cfg: ModelConfig, cell: ShapeCell, par: Parallel = None):
-    """All (non-param) inputs to the lowered step for a shape cell."""
-    B, S = cell.global_batch, cell.seq_len
-    if cell.kind == "train":
-        return {"batch": _train_batch_specs(cfg, B, S)}
-    if cell.kind == "prefill":
-        return {"batch": _train_batch_specs(cfg, B, S)}
-    if cell.kind == "decode":
-        return {
-            "state": decode_state_specs(cfg, B, S),
-            "token_ids": _sds((B, 1), jnp.int32),
-        }
-    raise ValueError(cell.kind)

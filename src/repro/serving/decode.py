@@ -23,8 +23,7 @@ device KV shards together through one ``sync_async``.
 cluster (``work[p]`` extra decode passes emulate a slow chip — the model
 really runs ``work`` times, wall-clock measured), Poisson arrivals, and
 lockstep rounds whose duration is the slowest live replica's measured
-time.  ``benchmarks/run.py serving_real_decode`` compares balanced vs
-unbalanced measured throughput on it.
+time.
 """
 from __future__ import annotations
 

@@ -16,6 +16,7 @@ Three layers, cheapest first:
 """
 import os
 import threading
+import time
 
 import multiprocessing as mp
 
@@ -281,6 +282,7 @@ def _failover_worker(backend):
     # allreduce completes, so survivors hit the death in phase 2.
     mm.register_range_move(col, LongRange(0, 4), 2)
     err = None
+    t0 = time.perf_counter()
     try:
         mm.sync()
     except PeerFailedError as e:
@@ -288,12 +290,12 @@ def _failover_worker(backend):
                "detail": str(e)}
     if err is None:
         return {"failed": False}
+    detect_s = time.perf_counter() - t0
     mm.abort_inflight()
 
-    import time as _t
-    t0 = _t.perf_counter()
+    t0 = time.perf_counter()
     new_g, stats = recover_dead_ranks(g, [col], transport=transport)
-    recovery_s = _t.perf_counter() - t0
+    recovery_s = time.perf_counter() - t0
 
     # finish degraded: another window over the survivors
     mm2 = CollectiveMoveManager(new_g, transport=transport)
@@ -311,6 +313,7 @@ def _failover_worker(backend):
         "rehomed": stats["rehomed"],
         "unrecovered": stats["unrecovered"],
         "total_after": total,
+        "detect_s": detect_s,
         "recovery_s": recovery_s,
         "live_places": new_g.local_places(),
         "members": new_g.members,
@@ -357,10 +360,12 @@ class TestThreeProcessFailover:
         assert failover[1]["live_places"] == (2, 3)
 
     def test_recovery_bounded_well_under_deadline(self, failover):
-        # recovery is collectives + local inserts — far under the 15 s
-        # collective deadline (the bench row asserts a tighter bound)
+        # the dead peer's closed pipe is detected at once, not after the
+        # 15 s collective deadline, and recovery is collectives + local
+        # inserts: crash to recovered stays far under the deadline
         for r in (0, 1):
-            assert failover[r]["recovery_s"] < 10.0
+            assert failover[r]["detect_s"] + failover[r]["recovery_s"] \
+                < 10.0
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,7 @@ and gather their final state; the tests then assert it is bit-identical
 to the same scenario run in-process over ``HostTransport``.
 """
 import os
+import pickle
 
 import numpy as np
 import pytest
@@ -70,12 +71,72 @@ def _snapshot_local(g, col, kv):
     return out
 
 
+SERVING_PLACES = 8
+SERVING_ROWS = 96
+
+
+def _serving_scenario(g, transport):
+    """Two chained depth-2 windows over every wire kind the serving tier
+    ships: a hot-shard DistArray, a DistIdMap of pickled metadata and a
+    DistIdMap of array pytrees (KV-like pages)."""
+    n, rows = SERVING_PLACES, SERVING_ROWS
+    col = DistArray(g, track=True)
+    if g.is_local(0):
+        col.add_chunk(0, LongRange(0, rows),
+                      np.arange(rows * 8, dtype=np.float64).reshape(rows, 8))
+    seqs, kv = DistIdMap(g), DistIdMap(g)
+    for k in range(4 * n):
+        p = k % n
+        if g.is_local(p):
+            seqs.put(p, k, ("seq", k, [k, k + 1]))
+            kv.put(p, k, {"pg": np.full((16, 4), float(k), np.float32),
+                          "meta": np.array([k, p], np.int32)})
+    mm = CollectiveMoveManager(g, transport=transport)
+    share = rows // 4
+    # window 1: spread the hot shard + key-rule moves from every place
+    for i, dest in enumerate((2, 4, 6)):
+        col.move_range_at_sync(LongRange(i * share, (i + 1) * share),
+                               dest, mm)
+    for p in range(n):
+        seqs.move_at_sync(p, lambda k: (int(k) * 5) % n, mm)
+        kv.move_at_sync(p, lambda k: (int(k) * 5) % n, mm)
+    mm.sync_async((col, seqs, kv), depth=2)
+    # window 2, chained behind window 1: count moves off the loaded
+    # places, a range move onto the last place, keys again
+    col.move_at_sync_count(2, share // 2, 1, mm)
+    col.move_at_sync_count(4, share // 2, 5, mm)
+    col.move_range_at_sync(LongRange(3 * share, rows), 7, mm)
+    for p in range(n):
+        seqs.move_at_sync(p, lambda k: (int(k) // 2) % n, mm)
+        kv.move_at_sync(p, lambda k: (int(k) // 2) % n, mm)
+    mm.sync_async((col, seqs, kv), depth=2)
+    mm.drain()
+    out = {}
+    for p in g.local_places():
+        h = col.handle(p)
+        out[p] = {
+            "ranges": [(r.start, r.end) for r in h.ranges()],
+            "rows": b"".join(h.chunks[r].tobytes() for r in h.ranges()),
+            "seqs": [(k, pickle.dumps(seqs.get(p, k)))
+                     for k in sorted(seqs.keys(p))],
+            "kv": [(k, kv.get(p, k)["pg"].tobytes(),
+                    kv.get(p, k)["meta"].tobytes())
+                   for k in sorted(kv.keys(p))],
+        }
+    return out, mm.last_counts_matrix.tolist()
+
+
 def _spmd_worker(backend):
     g = ProcessPlaceGroup(N_PLACES, backend)
     col, kv, mm = _run_scenario(g, DistributedTransport())
     snap: dict = {}
     for part in backend.allgather(_snapshot_local(g, col, kv)):
         snap.update(part)
+    serving, serving_counts = _serving_scenario(
+        ProcessPlaceGroup(SERVING_PLACES, backend), DistributedTransport())
+    serving_snap: dict = {}
+    for part in backend.allgather(serving):
+        serving_snap.update(part)
 
     # teamed ops across processes
     vec = [float(p * 10) if g.is_local(p) else -1.0 for p in g.members]
@@ -97,6 +158,8 @@ def _spmd_worker(backend):
         "kv_dist_owner_of_3": kv.get_distribution().owner_of(3),
         "allgather1": gathered.tolist(),
         "broadcast_seen": seen,
+        "serving_snap": serving_snap,
+        "serving_counts": serving_counts,
     }
 
 
@@ -109,10 +172,14 @@ def two_proc():
 def reference():
     g = PlaceGroup(N_PLACES)
     col, kv, mm = _run_scenario(g, HostTransport())
+    serving, serving_counts = _serving_scenario(PlaceGroup(SERVING_PLACES),
+                                                HostTransport())
     return {"snap": _snapshot_local(g, col, kv),
             "counts": mm.last_counts_matrix.tolist(),
             "dist_owner_of_9": col.get_distribution().owner_of(9),
-            "kv_dist_owner_of_3": kv.get_distribution().owner_of(3)}
+            "kv_dist_owner_of_3": kv.get_distribution().owner_of(3),
+            "serving_snap": serving,
+            "serving_counts": serving_counts}
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +211,19 @@ class TestTwoProcessParity:
                 == reference["dist_owner_of_9"]
             assert two_proc[r]["kv_dist_owner_of_3"] \
                 == reference["kv_dist_owner_of_3"]
+
+    def test_serving_shaped_depth2_windows_bit_identical(self, two_proc,
+                                                         reference):
+        # 8 places over 2 ranks, pickled and pytree map values beside
+        # array rows, two chained depth-2 windows
+        want = reference["serving_snap"]
+        assert sorted(want) == list(range(SERVING_PLACES))
+        assert sum(len(v["kv"]) for v in want.values()) \
+            == 4 * SERVING_PLACES
+        for r in (0, 1):
+            assert two_proc[r]["serving_snap"] == want
+            assert two_proc[r]["serving_counts"] \
+                == reference["serving_counts"]
 
     def test_allgather1_merges_authoritative_slots(self, two_proc):
         for r in (0, 1):

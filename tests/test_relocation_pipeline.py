@@ -74,6 +74,39 @@ class TestPipelineDepth2:
                            entry_multiset(col, 200), mm.syncs))
         assert finals[0] == finals[1]
 
+    def test_depth2_matches_depth1_keyed_pair_hops(self):
+        # the serving shape: two co-partitioned DistIdMaps (sequence
+        # metadata + KV pages) hand a key block on from replica to
+        # replica, one hop a window, by key rule; each hop's source only
+        # holds the block once the previous window delivered it
+        keys, block = 300, frozenset(range(150))
+        finals = []
+        for depth in (1, 2):
+            g = PlaceGroup(8)
+            seqs, kv = DistIdMap(g), DistIdMap(g)
+            for p in g.members:
+                seqs.handle(p)
+                kv.handle(p)
+            for k in range(keys):
+                seqs.put(0, k, np.full(4, k, np.float32))
+                kv.put(0, k, np.full((4, 16), k, np.float32))
+            mm = CollectiveMoveManager(g)
+            for src in range(3):
+                rule = lambda k, s=src: s + 1 if k in block else s  # noqa: E731
+                seqs.move_at_sync(src, rule, mm)
+                kv.move_at_sync(src, rule, mm)
+                mm.sync_async(update_dists=(seqs, kv), depth=depth)
+            mm.drain()
+            assert seqs.global_size() == keys and kv.global_size() == keys
+            for p in g.members:
+                assert sorted(seqs.keys(p)) == sorted(kv.keys(p))
+                for k in kv.keys(p):
+                    assert kv.get(p, k)[0, 0] == seqs.get(p, k)[0] == k
+            finals.append([sorted(seqs.keys(p)) for p in g.members])
+        assert finals[0] == finals[1]
+        assert finals[1][:4] == [list(range(150, keys)), [], [],
+                                 sorted(block)]
+
     def test_depth_bounds_inflight_windows(self):
         g, col = make_col(n=400)
         mm = CollectiveMoveManager(g)

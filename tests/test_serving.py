@@ -1,11 +1,7 @@
 """Elastic serving runtime: traffic-keyed rebalance convergence, router
 consistency across migrations, dead-replica re-homing, multi-collection
-windows, the ServingPool admission fix, and the benchmark smoke wiring."""
-import os
-import subprocess
-import sys
-from pathlib import Path
-
+windows, the ServingPool admission fix, and the three serving scenarios
+(steady, hot spot, failover) end to end."""
 import numpy as np
 import pytest
 
@@ -18,8 +14,6 @@ from repro.runtime.fault_tolerance import (ElasticWorld, FaultTolerantDriver,
 from repro.serving import (ElasticServingDriver, Router, Sequence,
                            ServingPool, ServingSim, TokenCostModel,
                            TrafficWorkload)
-
-REPO = Path(__file__).resolve().parents[1]
 
 
 def make_pool(n_places=2, per_place=8, tokens=32):
@@ -425,18 +419,68 @@ class TestServingPoolAdmission:
 
 
 # ---------------------------------------------------------------------------
-# benchmark smoke wiring (CI fast tier runs the row selector)
+# the three serving scenarios end to end: 8 replicas, GLB window every 4
+# steps, 8 windows of warm-up (then, for failover, replica 3 dies and 6
+# more windows run)
 # ---------------------------------------------------------------------------
-def test_bench_serving_smoke_selector():
-    # the sim rows only: the real-decode row (jit compiles) lives in the
-    # slow tier (tests/test_serving_real.py) and the CI bench step
-    out = subprocess.run(
-        [sys.executable, str(REPO / "benchmarks" / "run.py"), "--smoke",
-         "serving_steady", "serving_hotspot", "serving_failover"],
-        capture_output=True, text=True, timeout=300,
-        env={**os.environ, "PYTHONPATH": str(REPO / "src")},
-        cwd=str(REPO))
-    assert out.returncode == 0, out.stderr[-3000:]
-    for r in ("serving_steady", "serving_hotspot", "serving_failover"):
-        assert r in out.stdout, (r, out.stdout)
-    assert "lost=0" in out.stdout
+PERIOD, WARM_W, POST_W = 4, 8, 6
+SCENARIOS = {
+    "steady": dict(seed=1),
+    "hotspot": dict(speeds=(1, 1, 1, 1, 1, 0.4, 1, 1), admission="count",
+                    seed=1),
+    "failover": dict(fail_at={WARM_W * PERIOD: 3}, seed=2),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_serving_scenario_zero_lost(scenario):
+    kw = SCENARIOS[scenario]
+    failing = "fail_at" in kw
+    windows = WARM_W + (POST_W if failing else 0)
+    sim = ServingSim(n_replicas=8, arrival_rate=3.0, glb_period=PERIOD,
+                     **kw).run(windows * PERIOD)
+    d = sim.driver
+    # conservation: every admitted sequence is resident or completed
+    assert d.lost() == 0
+    if not failing:
+        assert d.evicted == [] and len(d.group.members) == 8
+        return
+    assert 3 not in d.group.members and d.evicted == [3]
+    # p95 back within 1.5x of the pre-failure level inside 10 windows
+    p95 = sim.window_p95()
+    baseline = float(np.mean(p95[WARM_W - 3:WARM_W]))
+    post = p95[WARM_W:]
+    recovery = next((i + 1 for i, p in enumerate(post)
+                     if p <= 1.5 * baseline), None)
+    assert recovery is not None and recovery <= 10, (baseline, post)
+
+
+def test_real_decode_hot_replica_spreads_device_kv():
+    """The jitted decode step drives the driver on a skewed residency: a
+    hot shard of long-lived sequences pinned to replica 0, which only
+    relocation can spread.  Balanced and unbalanced runs share one
+    engine and alternate window by window; neither loses a sequence,
+    and the balanced run's windows ship device KV with every sequence
+    beside its KV."""
+    from repro.serving import DecodeEngine, RealDecodeSim
+    from repro.serving.decode import serving_config
+
+    engine = DecodeEngine(serving_config(n_layers=1, d_model=32, d_ff=64,
+                                         vocab_size=64),
+                          s_cache=32, max_batch=4)
+    kw = dict(n_replicas=4, slots=24, preload=(0, 16), arrival_rate=2.0,
+              max_new_range=(16, 32), glb_period=PERIOD, seed=1,
+              engine=engine)
+    un = RealDecodeSim(balance=False, **kw)
+    ba = RealDecodeSim(**kw)
+    for _ in range(4):
+        un.run(PERIOD)
+        ba.run(PERIOD)
+    d = ba.driver
+    assert d.lost() == 0 and un.driver.lost() == 0
+    assert d.glb.stats.rebalances > 0 and d.glb.stats.bytes_moved > 0
+    for p in d.group.members:
+        assert sorted(d.seqs.keys(p)) == sorted(d.kv.keys(p))
+        assert all(v.on_device() for v in d.kv.handle(p).values())
+    assert sum(d.workload.kv_bytes_of(p) for p in d.group.members) > 0
+    assert ba.tokens > 0 and un.tokens > 0

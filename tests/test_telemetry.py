@@ -310,6 +310,40 @@ class TestRelocationInstrumentation:
         assert not any(k.startswith("transport.exchange_") for k in m)
         assert m["transport.host.payloads"] >= 1
 
+    def test_traced_steal_windows_export_spans_and_metric_keys(self,
+                                                               tmp_path):
+        # the host steal loop on the device transport: a relocation
+        # window per steal, each exchange on the jitted all_to_all
+        import json
+        from repro.core import (DeviceTransport, DistArrayWorkload,
+                                GLBConfig, GlobalLoadBalancer)
+
+        telemetry.enable()
+        g = PlaceGroup(N_PLACES)
+        col = DistArray(g, track=True)
+        col.add_chunk(0, LongRange(0, N_ROWS),
+                      np.arange(N_ROWS * WIDTH, dtype=np.float32)
+                      .reshape(N_ROWS, WIDTH))
+        for p in g.members:
+            col.handle(p)
+        glb = GlobalLoadBalancer(
+            g, DistArrayWorkload(col, transport=DeviceTransport()),
+            GLBConfig(random_steal_attempts=0))
+        assert glb.steal_loop(max_rounds=4)["stolen"] > 0
+        telemetry.write_chrome_trace(tmp_path / "trace.json")
+        doc = json.loads((tmp_path / "trace.json").read_text())
+        spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        names = {e["name"] for e in spans}
+        assert {"reloc.window", "transport.exchange"} <= names, names
+        # each exchange's wire bytes ride its span
+        assert any(e["args"].get("wire_bytes", 0) > 0 for e in spans
+                   if e["name"] == "transport.exchange")
+        m = telemetry.metrics_dict()
+        for key in ("reloc.window_s.p50", "reloc.window_s.p95",
+                    "glb.overlap_fraction", "transport.device.wire_bytes"):
+            assert key in m, f"missing metric {key}"
+        assert m["transport.device.wire_bytes"] > 0
+
     def test_uninstrumented_run_records_nothing(self):
         _one_window(PlaceGroup(N_PLACES), HostTransport())
         assert telemetry.tracer().records() == []

@@ -620,6 +620,39 @@ class TestDeviceStealTransport:
             assert np.asarray(rowsh).dtype == np.asarray(rowsd).dtype
         assert ch.get_distribution().items() == cd.get_distribution().items()
 
+    def test_ship_rows_matches_the_host_steal_loop(self):
+        # the hot-shard steal config: every row starts on place 0 and
+        # the lifeline steal spreads them.  Rows riding the device
+        # loop's all_to_all end at the same per-place loads as the host
+        # steal_pass loop's one window per steal, and none is lost
+        from repro.core import (DistArrayWorkload, GLBConfig,
+                                GlobalLoadBalancer)
+
+        def run(device_loop, transport):
+            g = PlaceGroup(8)
+            col = DistArray(g, track=True)
+            col.add_chunk(0, LongRange(0, 160),
+                          np.arange(160 * 8, dtype=np.float64)
+                          .reshape(160, 8))
+            for p in g.members:
+                col.handle(p)
+            glb = GlobalLoadBalancer(
+                g, DistArrayWorkload(col),
+                GLBConfig(lifeline="hypercube", random_steal_attempts=0,
+                          transport=transport), device_loop=device_loop)
+            return col, glb.steal_loop(max_rounds=12)
+
+        cd, rd = run(True, "device")
+        ch, rh = run(False, "host")
+        assert rd["device"] and not rh["device"]
+        assert [cd.local_size(p) for p in range(8)] \
+            == [ch.local_size(p) for p in range(8)]
+        assert rd["stolen"] == rh["stolen"] > 0
+        assert cd.global_size() == 160
+        rows = np.concatenate([np.asarray(cd.to_local_matrix(p)[0])
+                               for p in range(8)])
+        assert sorted(rows[:, 0]) == list(np.arange(160) * 8.0)
+
 
 # ---------------------------------------------------------------------------
 # the elastic serving driver on the device transport (wiring smoke)
